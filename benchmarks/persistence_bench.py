@@ -40,8 +40,13 @@ tail record is expected post-SIGKILL and is reported, not flagged.
 as ``BENCH_persistence.json`` so CI can archive the perf trajectory per
 commit.
 
-Wall-clock numbers matter on real accelerators; on CPU the kernels run
-interpreted and only launch counts + digests are meaningful.
+The parent never starts a JAX backend: it spawns children and reads
+their state dirs with the auditor, which computes nothing on a device.
+Only the children hold a device.  They run on the CPU backend
+(``JAX_PLATFORMS=cpu``) unless the caller sets ``JAX_PLATFORMS``
+itself, since a chip admits one process at a time and each child must
+then release it before the next starts.  On CPU the
+kernels run interpreted and only launch counts + digests are meaningful.
 """
 
 from __future__ import annotations
@@ -143,6 +148,12 @@ def _audit(state_dir: str, label: str) -> dict:
             "truncated_tail_bytes": report.truncated_tail_bytes}
 
 
+def _child_platforms() -> str:
+    """The children's JAX backend: the caller's ``JAX_PLATFORMS``, else
+    the CPU."""
+    return os.environ.get("JAX_PLATFORMS") or "cpu"
+
+
 def _run_child(state_dir: str, cfg, *, waves: int = -1, linger: bool = False,
                compact_on_start: bool = False) -> dict | None:
     """Run one engine process; SIGKILL it when it prints KILLME.
@@ -152,7 +163,7 @@ def _run_child(state_dir: str, cfg, *, waves: int = -1, linger: bool = False,
     """
     env = os.environ.copy()
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = _child_platforms()
     cmd = [sys.executable, os.path.abspath(__file__), "--child",
            "--state-dir", state_dir,
            "--requests", str(cfg.requests), "--n-fn", str(cfg.n_fn),
@@ -191,7 +202,8 @@ def _run_child(state_dir: str, cfg, *, waves: int = -1, linger: bool = False,
 def run(cfg) -> int:
     print(f"# {cfg.requests} requests, budget {cfg.samples} samples in "
           f"rounds of {cfg.round_samples} "
-          f"({cfg.samples // cfg.round_samples} rounds/stream)")
+          f"({cfg.samples // cfg.round_samples} rounds/stream); children "
+          f"run with JAX_PLATFORMS={_child_platforms()}")
     report: dict = {"bench": "persistence", "requests": cfg.requests,
                     "samples": cfg.samples,
                     "round_samples": cfg.round_samples, "phases": {}}

@@ -1,12 +1,14 @@
 """Linear multi-device scaling of multi-function integration (paper claim).
 
-On this 1-core container wall-clock cannot demonstrate scaling, so the
-claim is verified STRUCTURALLY, the same way the dry-run proves the LM
-cells: for device counts {1, 4, 16, 64, 256} the sharded MC program is
-lowered and its per-device sample count, per-device FLOPs and collective
-bytes are extracted.  Linear scaling == per-device compute ~ 1/P with
-collective bytes independent of N (only O(n_fn) for the final psum), which
-is exactly what the table shows.
+A CPU-only structural tool: it measures no time.  For device counts
+{1, 4, 16, 64, 256} it spawns one child per count with
+``JAX_PLATFORMS=cpu`` and that many forced host devices, lowers the
+sharded MC program there, and extracts its per-device sample count,
+per-device FLOPs and collective bytes.  Linear scaling == per-device
+compute ~ 1/P with collective bytes independent of N (only O(n_fn) for
+the final psum), which is exactly what the table shows.  The children
+never touch an accelerator, so the tool runs the same on a host with a
+chip.
 """
 
 from __future__ import annotations
@@ -25,11 +27,12 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.core import harmonic_family
 from repro.core.direct_mc import sharded_family_sums
+from repro.launch.mesh import make_mesh_for
 
 n_dev = %(n)d
 model = 4 if n_dev >= 16 else 1   # keep a function-sharding axis at scale
 data = n_dev // model
-mesh = jax.make_mesh((data, model), ("data", "model"))
+mesh = make_mesh_for(model_parallel=model)
 fam = harmonic_family(64, 4)
 N = 1 << 20
 
@@ -82,6 +85,7 @@ def run_scaling(device_counts=(1, 4, 16, 64, 256)) -> list[dict]:
         code = PROG % {"n": n, "src": SRC}
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)
+        env["JAX_PLATFORMS"] = "cpu"
         out = subprocess.run([sys.executable, "-c", code],
                              capture_output=True, text=True, timeout=600,
                              env=env)

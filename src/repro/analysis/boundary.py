@@ -8,9 +8,8 @@ in prose:
   version-drift shims, ``repro/kernels/pallas_compat.py`` and
   ``repro/compat.py``.  Everything else rides the shims, so a jax bump
   is a two-file change.
-* **BND002** — ``jax.shard_map`` (the new-API name) likewise: only
-  ``repro/compat.py`` may touch it, because the floor jax (0.4.37)
-  doesn't have it.
+* **BND002** — ``jax.shard_map`` likewise: only ``repro/compat.py``
+  may touch it, so one place sets its varying-axes check.
 * **PUR001** — modules under ``repro/kernels/`` and ``repro/core/``
   hold eval bodies and counter plumbing whose outputs must be a pure
   function of (key, counters, params): no wall-clock (``time``),
@@ -212,7 +211,7 @@ class _Checker(ast.NodeVisitor):
             elif chain == "jax.shard_map" and not self.shim:
                 self._flag("BND002", node,
                            "use repro.compat.shard_map, not "
-                           "jax.shard_map (absent on the floor jax)")
+                           "jax.shard_map")
             if self.pure:
                 if chain in ("np.random", "numpy.random") or chain.startswith(
                         ("np.random.", "numpy.random.")):

@@ -65,9 +65,8 @@ PROBE_DIMS = (1, 2, 4)
 PROBE_BINS = 4
 
 # jaxpr primitive-name fragments that mean "talks to the host".  The
-# ``effects`` set catches modern versions of these; the name scan keeps
-# the check meaningful across the jax floor (0.4.37) where some effects
-# plumbing differs.
+# ``effects`` set catches most of these; the name scan also catches a
+# host-talking primitive that declares no effect.
 _SIDE_EFFECT_FRAGMENTS = ("callback", "infeed", "outfeed", "debug")
 
 

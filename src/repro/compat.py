@@ -1,44 +1,21 @@
-"""Version-portability shims for JAX API drift (non-Pallas surface).
+"""The one import point for ``jax.shard_map`` (non-Pallas surface).
 
-The repo targets the jax.shard_map-era API, but must run on any jax from
-0.4.3x upward.  Every module that needs an API whose home has moved imports
-it from here instead of feature-testing locally, so there is exactly one
-place that knows about the drift.  (Pallas-specific drift lives in
-``repro.kernels.pallas_compat`` — the kernel layer's single import point.)
-
-Currently papered over:
-
-* ``jax.shard_map`` — promoted from ``jax.experimental.shard_map`` to the
-  top-level namespace in jax 0.6; older versions only have the
-  experimental path (whose extra ``check_rep`` knob we disable: the
-  replication checker in the 0.4.x line rejects some valid
-  collective-in-loop patterns that the promoted version accepts).
+Every module that maps a function over a mesh imports :func:`shard_map`
+from here, so one place decides how the varying-axes check is set.
+(Pallas imports live in ``repro.kernels.pallas_compat`` — the kernel
+layer's single import point.)
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """`jax.shard_map` on new jax, `jax.experimental.shard_map` on old.
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
+    """``jax.shard_map`` with the varying-axes check as an argument.
 
-    ``check_vma``: the varying-axes checker toggle, named ``check_vma`` on
-    new jax and ``check_rep`` on the experimental version.  ``None`` means
-    "whatever the version's default is" (new jax) / disabled (old jax).
+    Pass ``check_vma=False`` around a ``pallas_call``: its ``out_shape``
+    carries no varying-axes type, which the check refuses.
     """
-    if hasattr(jax, "shard_map"):
-        kwargs = {}
-        if check_vma is not None:
-            params = inspect.signature(jax.shard_map).parameters
-            for name in ("check_vma", "check_rep"):
-                if name in params:
-                    kwargs[name] = check_vma
-                    break
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -241,6 +241,8 @@ def sharded_family_sums(
         local, mesh=mesh,
         in_specs=(spec_params, fn_spec, fn_spec),
         out_specs=(fn_spec, fn_spec, rep),
+        # the registered-kernel impl is a pallas_call
+        check_vma=False,
     )(fam.params, fam.domains, fn_ids)
     s1, s2, n = out
     return SumsState(s1=s1, s2=s2, n=n), fam

@@ -90,8 +90,11 @@ def bits_to_uniform(bits):
 
     Uses the top 24 bits so the result is exactly representable in f32 and
     the mapping matches what the Pallas kernel computes with the same ops.
+    The cast goes through int32, which is exact below 2^24: Mosaic has no
+    uint32 -> float32 conversion.
     """
-    return (bits >> np.uint32(8)).astype(jnp.float32) * _INV_2_24
+    top = (bits >> np.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * _INV_2_24
 
 
 def fold_key(seed: int, stream: int = 0) -> tuple[np.uint32, np.uint32]:

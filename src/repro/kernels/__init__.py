@@ -3,8 +3,7 @@
 Layout:
 
 * ``pallas_compat`` — the single import point for ``pl``/``pltpu``.
-  Papers over JAX API drift (``CompilerParams`` vs ``TPUCompilerParams``)
-  and owns interpret-mode selection: compiled Mosaic on TPU, the Pallas
+  Owns interpret-mode selection: compiled Mosaic on TPU, the Pallas
   interpreter everywhere else, so the whole subsystem runs (and is
   tested) on CPU-only hosts.
 * ``template`` — the shared grid / in-VMEM sampling / accumulator

@@ -22,7 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.pallas_compat import compiler_params, pl
+from repro.kernels.pallas_compat import pl, pltpu
 from repro.kernels.template import accumulate
 
 R_BLK = 8     # strata rows per grid step
@@ -68,7 +68,7 @@ def moments_pallas(values, *, interpret: bool):
         in_specs=[pl.BlockSpec((R_BLK, C_BLK), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((R_BLK, 3), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, 3), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="stratum_moments",

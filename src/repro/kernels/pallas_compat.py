@@ -1,18 +1,14 @@
 """Single import point for Pallas across the kernel subsystem.
 
 Every kernel module imports ``pl``/``pltpu`` from here — never from
-``jax.experimental`` directly — so JAX API drift is papered over exactly
-once:
+``jax.experimental`` directly — so a JAX API move is absorbed exactly
+once.
 
-* ``CompilerParams``: the TPU compiler-params class was named
-  ``TPUCompilerParams`` through the jax 0.4/0.5 line and renamed to
-  ``CompilerParams`` in 0.6.  :func:`compiler_params` builds whichever
-  exists (both accept ``dimension_semantics``).
-* Interpret mode: real Mosaic lowering only exists on TPU.
-  :func:`should_interpret` is the one place that decides when kernels run
-  under the Pallas interpreter (CPU CI containers, GPU hosts without a
-  Mosaic backend) vs compiled; ops-layer wrappers default their
-  ``interpret`` argument from it.
+Interpret mode: real Mosaic lowering only exists on TPU.
+:func:`should_interpret` is the one place that decides when kernels run
+under the Pallas interpreter (the CPU test runs) vs compiled; ops-layer
+wrappers default their ``interpret`` argument from it.  On a TPU
+backend nothing picks interpret mode.
 
 If a future jax moves ``pl``/``pltpu`` themselves, only this module
 changes.
@@ -23,15 +19,6 @@ from __future__ import annotations
 import jax
 from jax.experimental import pallas as pl  # noqa: F401  (re-export)
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (re-export)
-
-# jax >= 0.6 name, falling back to the 0.4/0.5 name.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
-
-def compiler_params(*, dimension_semantics=None, **kwargs):
-    """Version-portable ``pltpu.CompilerParams`` constructor."""
-    return _CompilerParams(dimension_semantics=dimension_semantics, **kwargs)
 
 
 def should_interpret() -> bool:

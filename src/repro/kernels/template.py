@@ -98,8 +98,7 @@ import jax.numpy as jnp
 
 from repro.core import domains as domains_lib
 from repro.core import rng as rng_lib
-from repro.kernels.pallas_compat import (compiler_params, pl, pltpu,
-                                         resolve_interpret)
+from repro.kernels.pallas_compat import pl, pltpu, resolve_interpret
 
 # Sample tile: 16 sublanes x 128 lanes = 2048 samples per grid step.
 S_ROWS = 16
@@ -427,13 +426,17 @@ def _fused_kernel(*refs, dim: int, bodies: tuple, sampler: str,
     """One (function-block, round, sample-block) grid cell.
 
     Ref order: scalars, fn_ids, [form_ids], [round_base], [dirvecs],
-    packed, lo, hi, out.
+    packed, lo, hi, out.  The SMEM operands other than ``scalars`` are
+    whole arrays indexed by the function-block id ``i``: Mosaic refuses
+    a rank-1 SMEM block that is neither the whole array nor a multiple
+    of 128 words.
       scalars: SMEM u32[4|5] = (k0, k1, sample_offset, n_valid
                [, round_stride — required when n_rounds > 1])
-      fn_ids:  SMEM u32[F_BLK] global function ids (RNG counters)
-      form_ids: SMEM i32[1] body index of this function block (multi-form)
-      round_base: SMEM u32[1] additional per-block sample offset (fused
-               streams at different refinement depths)
+      fn_ids:  SMEM u32[n_fn_pad] global function ids (RNG counters)
+      form_ids: SMEM i32[n_blocks] body index per function block
+               (multi-form)
+      round_base: SMEM u32[n_blocks] additional per-block sample offset
+               (fused streams at different refinement depths)
       dirvecs: VMEM u32[dim, 32] Sobol direction vectors (sampler="sobol")
       packed:  VMEM f32[F_BLK, n_cols] form-packed parameters
       lo/hi:   VMEM f32[F_BLK, dim] domain boxes
@@ -447,13 +450,14 @@ def _fused_kernel(*refs, dim: int, bodies: tuple, sampler: str,
     v_ref = next(it) if sampler == "sobol" else None
     packed_ref, lo_ref, hi_ref, out_ref = it
 
+    i = pl.program_id(0)
     j = pl.program_id(2)
     k0 = scalars_ref[0]
     k1 = scalars_ref[1]
     sample_offset = scalars_ref[2]
     n_valid = scalars_ref[3]
     if has_round_base:
-        sample_offset = sample_offset + rbase_ref[0]
+        sample_offset = sample_offset + rbase_ref[i]
     if n_rounds > 1:
         # round r's window starts round_stride counters after round r-1's;
         # uint32 adds are exact, so this matches a single-round launch at
@@ -473,7 +477,7 @@ def _fused_kernel(*refs, dim: int, bodies: tuple, sampler: str,
     def eval_block(body):
         parts = []
         for f in range(F_BLK):
-            fid = fn_ids_ref[f]
+            fid = fn_ids_ref[i * F_BLK + f]
 
             def draw(d, f=f, fid=fid):
                 c1 = fid * jnp.uint32(rng_lib.DIM_STRIDE) + jnp.uint32(d)
@@ -495,7 +499,7 @@ def _fused_kernel(*refs, dim: int, bodies: tuple, sampler: str,
 
     if has_forms and len(bodies) > 1:
         part = jax.lax.switch(
-            form_ref[0], [functools.partial(eval_block, b) for b in bodies])
+            form_ref[i], [functools.partial(eval_block, b) for b in bodies])
     else:
         part = eval_block(bodies[0])
 
@@ -544,21 +548,17 @@ def fused_mc_pallas(scalars, fn_ids, packed, lo, hi, form_ids=None,
     grid = (n_fn_pad // F_BLK, n_rounds, n_sample_blocks)
     fn_blk = lambda i, r, j: (i, 0)
 
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),                    # scalars
-        pl.BlockSpec((F_BLK,), lambda i, r, j: (i,),
-                     memory_space=pltpu.SMEM),                    # fn_ids
-    ]
+    # scalars, fn_ids, form_ids and round_base are whole SMEM arrays
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    in_specs = [smem, smem]
     args = [scalars, fn_ids]
     has_forms = form_ids is not None
     if has_forms:
-        in_specs.append(pl.BlockSpec((1,), lambda i, r, j: (i,),
-                                     memory_space=pltpu.SMEM))    # form_ids
+        in_specs.append(smem)
         args.append(form_ids)
     has_round_base = round_base is not None
     if has_round_base:
-        in_specs.append(pl.BlockSpec((1,), lambda i, r, j: (i,),
-                                     memory_space=pltpu.SMEM))    # round_base
+        in_specs.append(smem)
         args.append(round_base)
     if sampler == "sobol":
         in_specs.append(pl.BlockSpec((dim, 32), lambda i, r, j: (0, 0)))
@@ -579,7 +579,7 @@ def fused_mc_pallas(scalars, fn_ids, packed, lo, hi, form_ids=None,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, F_BLK, 2), lambda i, r, j: (r, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_rounds, n_fn_pad, 2), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # function blocks and rounds write independent output blocks;
             # the sample axis revisits its round's accumulator block and
             # must stay sequential

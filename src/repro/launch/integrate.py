@@ -20,6 +20,8 @@ from repro.distributed.fault_tolerance import StepWatchdog, run_with_restarts
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if os.environ.get("REPRO_MULTIHOST"):
         from repro.launch.multihost import initialize_if_needed
         initialize_if_needed()

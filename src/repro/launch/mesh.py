@@ -4,13 +4,22 @@
 importing this module never touches jax device state — the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first jax
 init, and nothing here may run earlier.
+
+Every mesh here has Auto axes.  ``jax.make_mesh`` defaults to Explicit
+axes, under which slicing a sharded result on the host (the engine cuts
+each family's rows out of a bucket) raises ``ShardingTypeError``.
 """
 
 from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes, devices=None) -> Mesh:
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -28,7 +37,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}; have {len(devices)} "
             "(the dry-run must set XLA_FLAGS before any jax import)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_mesh_for(n_devices: int | None = None, model_parallel: int = 1,
@@ -41,9 +50,9 @@ def make_mesh_for(n_devices: int | None = None, model_parallel: int = 1,
                          f"model={model_parallel} x pods={pods}")
     data = n // (model_parallel * pods)
     if pods > 1:
-        return jax.make_mesh((pods, data, model_parallel),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((data, model_parallel), ("data", "model"))
+        return _auto_mesh((pods, data, model_parallel),
+                          ("pod", "data", "model"))
+    return _auto_mesh((data, model_parallel), ("data", "model"))
 
 
 def mesh_info(mesh: Mesh) -> dict:

@@ -9,7 +9,9 @@ before any other jax usage by the pod entrypoints
 
 Supported environments:
   * Cloud TPU VMs / GKE: coordinator + process id from the TPU metadata
-    (jax.distributed.initialize() with no args autodetects).
+    (jax.distributed.initialize() with no args autodetects) when
+    TPU_WORKER_HOSTNAMES names several hosts or
+    MEGASCALE_COORDINATOR_ADDRESS is set.
   * Generic MPI-ish: REPRO_COORD, REPRO_NUM_PROCS, REPRO_PROC_ID env vars.
 
 Elastic note: on restart with a different number of hosts, initialise with
@@ -24,27 +26,24 @@ import os
 
 def initialize_if_needed(verbose: bool = True) -> bool:
     """Initialise jax.distributed from the environment. Returns True if a
-    multi-host runtime was set up, False for single-process runs."""
-    import jax
+    multi-host runtime was set up, False for single-process runs.
 
+    When the environment names several hosts, a failed initialisation
+    raises: carrying on as one host would run a different job.
+    """
     coord = os.environ.get("REPRO_COORD")
     nprocs = os.environ.get("REPRO_NUM_PROCS")
     pid = os.environ.get("REPRO_PROC_ID")
-    try:
-        if coord and nprocs and pid:
-            jax.distributed.initialize(
-                coordinator_address=coord,
-                num_processes=int(nprocs),
-                process_id=int(pid))
-        elif os.environ.get("TPU_WORKER_HOSTNAMES") or \
-                os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"):
-            jax.distributed.initialize()   # TPU metadata autodetect
-        else:
-            return False
-    except Exception as e:  # single-host fallback keeps dev loops working
-        if verbose:
-            print(f"[multihost] distributed init skipped: {e}")
+    workers = os.environ.get("TPU_WORKER_HOSTNAMES", "")
+    if coord and nprocs and pid:
+        kwargs = {"coordinator_address": coord,
+                  "num_processes": int(nprocs), "process_id": int(pid)}
+    elif "," in workers or os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"):
+        kwargs = {}                        # TPU metadata autodetect
+    else:
         return False
+    import jax
+    jax.distributed.initialize(**kwargs)
     if verbose:
         print(f"[multihost] process {jax.process_index()}/"
               f"{jax.process_count()}: {jax.local_device_count()} local / "

@@ -119,6 +119,8 @@ def demo_workload(n_requests: int, *, n_fn: int = 8,
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--n-fn", type=int, default=8,

@@ -13,8 +13,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import (MultiFunctionSpec, ZMCMultiFunctions,
                         harmonic_analytic, harmonic_family)
+from repro.launch.mesh import make_mesh_for
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh_for(model_parallel=2)            # (data=4, model=2)
 spec = MultiFunctionSpec.from_families([harmonic_family(10, 4)])
 zm = ZMCMultiFunctions(spec, n_samples=200_000, seed=5, mesh=mesh)
 r = zm.evaluate(num_trials=2)
@@ -23,7 +24,7 @@ pulls = np.abs(r.trial_mean - exact) / np.maximum(r.stderrs.mean(0), 1e-12)
 assert np.all(pulls < 5.0), pulls
 
 # mesh-shape invariance of the estimate (same counters, same totals)
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+mesh2 = make_mesh_for(model_parallel=4)           # (data=2, model=4)
 zm2 = ZMCMultiFunctions(spec, n_samples=200_000, seed=5, mesh=mesh2)
 r2 = zm2.evaluate(num_trials=1)
 # sample partition differs (4 vs 2 sample shards) -> statistically equal
