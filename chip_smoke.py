@@ -94,19 +94,6 @@ def _require(cond: bool, msg: str) -> None:
         raise SmokeFailure(msg)
 
 
-class CompileClock:
-    """Sums the host seconds JAX spends in backend compiles."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.count = 0
-
-    def __call__(self, event: str, duration: float, **kwargs) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.count += 1
-
-
 def paper_request(sz: Sizes):
     from repro.core import harmonic_family
     from repro.service import IntegrationRequest
@@ -150,11 +137,11 @@ def request_mix(sz: Sizes) -> dict:
     return reqs
 
 
-def make_engine(sz: Sizes, mesh=None):
+def make_engine(sz: Sizes, mesh=None, obs=None):
     from repro.service import IntegrationEngine
     return IntegrationEngine(seed=0, round_samples=sz.round_samples,
                              use_kernel=True, mesh=mesh,
-                             max_rounds_per_wave=sz.rounds)
+                             max_rounds_per_wave=sz.rounds, obs=obs)
 
 
 def serve(engine, requests: dict) -> dict:
@@ -239,7 +226,7 @@ def device_line() -> dict:
             "count": len(jax.devices())}
 
 
-def one_chip(sz: Sizes = FULL) -> None:
+def one_chip(sz: Sizes = FULL, obs=None) -> None:
     """Serve the request mix on one device and check every result."""
     import numpy as np
 
@@ -247,7 +234,7 @@ def one_chip(sz: Sizes = FULL) -> None:
     from repro.core import genz
     from repro.kernels import template
 
-    engine = make_engine(sz)
+    engine = make_engine(sz, obs=obs)
     reqs = request_mix(sz)
     template.reset_launch_count()
     t0 = time.perf_counter()
@@ -297,7 +284,7 @@ def one_chip(sz: Sizes = FULL) -> None:
     engine_health(engine)
 
 
-def four_chips(sz: Sizes = FULL) -> None:
+def four_chips(sz: Sizes = FULL, obs=None) -> None:
     """Serve the paper batch on the ``serve_integrals --mesh`` mesh and on
     one device; the two must agree to f32 rounding."""
     import jax
@@ -313,7 +300,7 @@ def four_chips(sz: Sizes = FULL) -> None:
 
     results = {}
     for label, m in (("mesh", mesh), ("one device", None)):
-        engine = make_engine(sz, mesh=m)
+        engine = make_engine(sz, mesh=m, obs=obs)
         template.reset_launch_count()
         t0 = time.perf_counter()
         res = serve(engine, {"paper": paper_request(sz)})["paper"]
@@ -344,9 +331,9 @@ def main() -> int:
     from repro.launch.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache()
     import jax
-    import jax.monitoring
 
     from repro.kernels.pallas_compat import should_interpret
+    from repro.obs import Observability
 
     if jax.default_backend() != "tpu":
         print(f"chip_smoke: JAX backend is {jax.default_backend()!r}, not "
@@ -359,19 +346,26 @@ def main() -> int:
     print(f"device: {dev['kind']} x {dev['count']} ({dev['platform']}); "
           f"compile cache: {cache_dir}")
 
-    clock = CompileClock()
-    jax.monitoring.register_event_duration_secs_listener(clock)
+    # every engine shares one bundle: its zmc_backend_compiles_total and
+    # zmc_compile_seconds count the whole run's compiles
+    obs = Observability.disabled()
     t0 = time.perf_counter()
     try:
         if args.chips == 4:
-            four_chips()
+            four_chips(obs=obs)
         else:
-            one_chip()
+            one_chip(obs=obs)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
-    print(f"compile: {clock.count} backend compiles, {clock.seconds:.3f} s; "
-          f"total {time.perf_counter() - t0:.3f} s host clock")
+    finally:
+        obs.close()
+    secs = obs.m["compile_seconds"]
+    print(f"compile: {obs.m['backend_compiles'].value():g} backend "
+          f"compiles; seconds by phase: "
+          + ", ".join(f"{ph} {secs.sum(phase=ph):.3f}"
+                      for ph in ("trace", "lower", "backend"))
+          + f"; total {time.perf_counter() - t0:.3f} s host clock")
     print(json.dumps({"ok": True, "device": dev}))
     return 0
 

@@ -47,6 +47,7 @@ cache's in-order fold and resume invariants depend on this).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -232,8 +233,16 @@ def _round_base_for(bucket: _Bucket, start_rounds, round_samples: int):
     return jnp.asarray(base)
 
 
+def _untimed(name: str, *labels):
+    return _UNTIMED
+
+
+_UNTIMED = contextlib.nullcontext()
+
+
 def eval_plan_rounds(plan: FusionPlan, round_samples: int, n_rounds: int,
-                     key, *, start_rounds, interpret: bool | None = None):
+                     key, *, start_rounds, interpret: bool | None = None,
+                     part=None):
     """R consecutive fixed-size rounds of every bucket, ONE launch each.
 
     Args:
@@ -242,6 +251,12 @@ def eval_plan_rounds(plan: FusionPlan, round_samples: int, n_rounds: int,
       n_rounds: consecutive rounds to evaluate per family.
       start_rounds: family_index -> absolute first round index; families
         may start at different depths (fused top-ups).
+      part: ``part(name, label)`` -> context manager timing one step:
+        ``dispatch`` of the shared scalars (label ``scalars``), then per
+        bucket its ``dispatch`` (operands and ``fused_mc_pallas``) and
+        ``unpack`` (per-round slicing), labelled with the kernel's name;
+        a trace span's ``part`` (:mod:`repro.obs.trace`).  None times
+        nothing.
     Returns:
       {family_index: (SumsState, ...)} — ``n_rounds`` states in round
       order, each bit-identical to the single-round
@@ -251,30 +266,36 @@ def eval_plan_rounds(plan: FusionPlan, round_samples: int, n_rounds: int,
 
     interpret = resolve_interpret(interpret)
     n_sample_blocks = max(1, math.ceil(int(round_samples) / S_BLK))
-    scalars = template.pack_scalars(key, 0, round_samples,
-                                    round_stride=round_samples)
+    part = part or _untimed
+    with part("dispatch", "scalars"):
+        scalars = template.pack_scalars(key, 0, round_samples,
+                                        round_stride=round_samples)
 
     out: dict[int, tuple] = {}
     for bucket in plan.buckets:
-        dirvecs = None
-        if plan.sampler == "sobol":
-            from repro.core.sobol import direction_vectors
-            dirvecs = jnp.asarray(direction_vectors(bucket.dim))
-        round_base = _round_base_for(bucket, start_rounds, round_samples)
-        template.record_launch()
-        sums = template.fused_mc_pallas(
-            scalars, bucket.fn_ids, bucket.packed, bucket.lo, bucket.hi,
-            form_ids=bucket.form_ids, round_base=round_base,
-            dirvecs=dirvecs, dim=bucket.dim,
-            n_sample_blocks=n_sample_blocks, bodies=bucket.bodies,
-            n_rounds=n_rounds, sampler=plan.sampler, interpret=interpret,
-            name=f"{bucket.name}_r{n_rounds}")
-        for sl in bucket.slices:
-            rows = sums[:, sl.row_start:sl.row_start + sl.n_fn]
-            out[sl.family_index] = tuple(
-                SumsState(s1=rows[r, :, 0], s2=rows[r, :, 1],
-                          n=jnp.float32(round_samples))
-                for r in range(n_rounds))
+        name = f"{bucket.name}_r{n_rounds}"
+        with part("dispatch", name):
+            dirvecs = None
+            if plan.sampler == "sobol":
+                from repro.core.sobol import direction_vectors
+                dirvecs = jnp.asarray(direction_vectors(bucket.dim))
+            round_base = _round_base_for(bucket, start_rounds,
+                                         round_samples)
+            template.record_launch()
+            sums = template.fused_mc_pallas(
+                scalars, bucket.fn_ids, bucket.packed, bucket.lo, bucket.hi,
+                form_ids=bucket.form_ids, round_base=round_base,
+                dirvecs=dirvecs, dim=bucket.dim,
+                n_sample_blocks=n_sample_blocks, bodies=bucket.bodies,
+                n_rounds=n_rounds, sampler=plan.sampler,
+                interpret=interpret, name=name)
+        with part("unpack", name):
+            for sl in bucket.slices:
+                rows = sums[:, sl.row_start:sl.row_start + sl.n_fn]
+                out[sl.family_index] = tuple(
+                    SumsState(s1=rows[r, :, 0], s2=rows[r, :, 1],
+                              n=jnp.float32(round_samples))
+                    for r in range(n_rounds))
     return out
 
 
@@ -386,9 +407,9 @@ def sharded_eval_plan(plan: FusionPlan, n_samples: int, key, mesh, *,
 def sharded_eval_plan_rounds(plan: FusionPlan, round_samples: int,
                              n_rounds: int, key, mesh, *, start_rounds,
                              fn_axis: str = "model", sample_axes=("data",),
-                             interpret: bool | None = None):
+                             interpret: bool | None = None, part=None):
     """Mesh variant of :func:`eval_plan_rounds`: R rounds x B buckets in
-    B launches, *inside* ``shard_map``.
+    B launches, *inside* ``shard_map``; ``part`` as there.
 
     Each sample-axis shard evaluates its window of every round (the last
     shard masks the tail, so each round draws exactly ``round_samples``
@@ -411,51 +432,58 @@ def sharded_eval_plan_rounds(plan: FusionPlan, round_samples: int,
     k0, k1 = key
     fs = P(fn_axis)
 
+    part = part or _untimed
     out: dict[int, tuple] = {}
     for bucket in plan.buckets:
-        sb = _shard_bucket(bucket, fn_par)
-        round_base = _round_base_for(sb, start_rounds, round_samples)
-        dirvecs = None
-        if plan.sampler == "sobol":
-            from repro.core.sobol import direction_vectors
-            dirvecs = jnp.asarray(direction_vectors(sb.dim))
+        name = f"{bucket.name}_r{n_rounds}_sharded"
+        with part("dispatch", name):
+            sb = _shard_bucket(bucket, fn_par)
+            round_base = _round_base_for(sb, start_rounds, round_samples)
+            dirvecs = None
+            if plan.sampler == "sobol":
+                from repro.core.sobol import direction_vectors
+                dirvecs = jnp.asarray(direction_vectors(sb.dim))
 
-        def local(fn_ids, packed, lo, hi, round_base, form_ids, *,
-                  _bucket=sb, _dirvecs=dirvecs):
-            idx = jnp.uint32(0)
-            mult = 1
-            for a in reversed(sample_axes):
-                idx = idx + jnp.uint32(jax.lax.axis_index(a)) * jnp.uint32(mult)
-                mult *= mesh.shape[a]
-            start = jnp.minimum(idx * jnp.uint32(per_shard),
-                                jnp.uint32(round_samples))
-            n_local = jnp.minimum(jnp.uint32(round_samples) - start,
-                                  jnp.uint32(per_shard))
-            scalars = template.pack_scalars((k0, k1), start, n_local,
-                                            round_stride=round_samples)
-            sums = template.fused_mc_pallas(
-                scalars, fn_ids, packed, lo, hi, form_ids=form_ids,
-                round_base=round_base, dirvecs=_dirvecs, dim=_bucket.dim,
-                n_sample_blocks=n_sample_blocks, bodies=_bucket.bodies,
-                n_rounds=n_rounds, sampler=plan.sampler,
-                interpret=interpret,
-                name=f"{_bucket.name}_r{n_rounds}_sharded")
-            return jax.lax.psum(sums, sample_axes)
+            def local(fn_ids, packed, lo, hi, round_base, form_ids, *,
+                      _bucket=sb, _dirvecs=dirvecs):
+                idx = jnp.uint32(0)
+                mult = 1
+                for a in reversed(sample_axes):
+                    idx = idx + (jnp.uint32(jax.lax.axis_index(a))
+                                 * jnp.uint32(mult))
+                    mult *= mesh.shape[a]
+                start = jnp.minimum(idx * jnp.uint32(per_shard),
+                                    jnp.uint32(round_samples))
+                n_local = jnp.minimum(jnp.uint32(round_samples) - start,
+                                      jnp.uint32(per_shard))
+                scalars = template.pack_scalars((k0, k1), start, n_local,
+                                                round_stride=round_samples)
+                sums = template.fused_mc_pallas(
+                    scalars, fn_ids, packed, lo, hi, form_ids=form_ids,
+                    round_base=round_base, dirvecs=_dirvecs,
+                    dim=_bucket.dim,
+                    n_sample_blocks=n_sample_blocks, bodies=_bucket.bodies,
+                    n_rounds=n_rounds, sampler=plan.sampler,
+                    interpret=interpret,
+                    name=f"{_bucket.name}_r{n_rounds}_sharded")
+                return jax.lax.psum(sums, sample_axes)
 
-        in_specs = [fs, fs, fs, fs, fs]
-        args = [sb.fn_ids, sb.packed, sb.lo, sb.hi, round_base]
-        if sb.form_ids is not None:
-            in_specs.append(fs)
-            args.append(sb.form_ids)
-        else:
-            local = functools.partial(local, form_ids=None)
-        template.record_launch()
-        sums = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                         out_specs=P(None, fn_axis), check_vma=False)(*args)
-        for sl in bucket.slices:
-            rows = sums[:, sl.row_start:sl.row_start + sl.n_fn]
-            out[sl.family_index] = tuple(
-                SumsState(s1=rows[r, :, 0], s2=rows[r, :, 1],
-                          n=jnp.float32(int(round_samples)))
-                for r in range(n_rounds))
+            in_specs = [fs, fs, fs, fs, fs]
+            args = [sb.fn_ids, sb.packed, sb.lo, sb.hi, round_base]
+            if sb.form_ids is not None:
+                in_specs.append(fs)
+                args.append(sb.form_ids)
+            else:
+                local = functools.partial(local, form_ids=None)
+            template.record_launch()
+            sums = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                             out_specs=P(None, fn_axis),
+                             check_vma=False)(*args)
+        with part("unpack", name):
+            for sl in bucket.slices:
+                rows = sums[:, sl.row_start:sl.row_start + sl.n_fn]
+                out[sl.family_index] = tuple(
+                    SumsState(s1=rows[r, :, 0], s2=rows[r, :, 1],
+                              n=jnp.float32(int(round_samples)))
+                    for r in range(n_rounds))
     return out

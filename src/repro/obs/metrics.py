@@ -300,6 +300,18 @@ def service_metrics(registry: MetricsRegistry) -> dict:
     zmc_grid_refits_total            grid refits (epoch openings beyond the
                                      first; agrees with ``grid_refit`` trace
                                      events)
+    zmc_grid_pilots_total            grid pilots run (one per fit: at submit
+                                     for epoch 1, in ``plan`` for a refit)
+    zmc_plan_cache_hits_total        fused groups whose fusion plan was
+                                     cached (``RoundBatcher._plan_for``)
+    zmc_plan_cache_misses_total      fused groups that built and uploaded a
+                                     fresh plan (``multi.plan_spec``)
+    zmc_d2h_copies_total             device-to-host reads in ``transfer``
+                                     (three per stream and round)
+    zmc_backend_compiles_total       XLA backend compiles JAX reported
+                                     (process-wide, from ``jax.monitoring``)
+    zmc_compile_seconds              histogram {phase=trace|lower|backend}:
+                                     seconds per compile phase, as above
     ==============================  =============================================
     """
     return {
@@ -387,4 +399,23 @@ def service_metrics(registry: MetricsRegistry) -> dict:
         "grid_refits": registry.counter(
             "zmc_grid_refits_total",
             "importance-grid refits (epoch openings beyond the first)"),
+        "grid_pilots": registry.counter(
+            "zmc_grid_pilots_total",
+            "importance-grid pilots run (epoch-1 fits at submit and refits)"),
+        "plan_cache_hits": registry.counter(
+            "zmc_plan_cache_hits_total",
+            "fused launch groups served by a cached fusion plan"),
+        "plan_cache_misses": registry.counter(
+            "zmc_plan_cache_misses_total",
+            "fused launch groups that built a fresh fusion plan"),
+        "d2h_copies": registry.counter(
+            "zmc_d2h_copies_total",
+            "device-to-host reads of wave sums in the transfer stage"),
+        "backend_compiles": registry.counter(
+            "zmc_backend_compiles_total",
+            "XLA backend compiles reported by jax.monitoring"),
+        "compile_seconds": registry.histogram(
+            "zmc_compile_seconds",
+            "seconds per JAX compile phase (trace, lower, backend)",
+            ("phase",)),
     }

@@ -20,9 +20,21 @@ enters a ``jax.profiler.TraceAnnotation`` so the same stage names line
 up inside a device profile (XProf/TensorBoard) — lazily imported and
 silently skipped where unavailable.
 
+A span can carry **parts**: timings inside it kept as an arg of the
+span (``args["parts"]``, each ``[name, offset_us, dur_us, *labels]``
+with the offset from the span's start), not as child spans, so a
+stage's self time is what it was.  ``with span.part("dispatch",
+bucket):`` times one; :func:`current_part` names the part open on the
+calling thread, which is how a compile JAX reports during a dispatch
+becomes a ``compile`` part of that dispatch's span.  Inside ``with
+tracer.wave(seq):`` every span the thread opens carries ``wave=seq``.
+:meth:`Tracer.complete` emits a span after the fact, on any thread's
+tid (a request's submit-to-finish span, stamped on the client's).
+
 When tracing is off the engine holds the module-level :data:`NULL`
-tracer: ``span()`` returns one shared no-op context manager, so the
-disabled hot path costs two attribute lookups per stage per wave.
+tracer: ``span()`` returns one shared no-op context manager (whose
+``part()`` is itself), so the disabled hot path costs two attribute
+lookups per stage per wave and reads no clock.
 
 Timestamps come from :mod:`repro.obs.clock` (monotonic ns -> trace µs)
 — never from ``time`` directly (rule OBS001).
@@ -46,6 +58,11 @@ STAGES = ("plan", "launch", "device_execute", "transfer", "deposit",
           "wal_commit")
 
 
+def current_tid() -> int:
+    """The calling thread's id as trace events carry it."""
+    return threading.get_ident() & 0xFFFF
+
+
 class _NullSpan:
     __slots__ = ()
 
@@ -54,6 +71,12 @@ class _NullSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def part(self, name: str, *labels):
+        return self
+
+    def set(self, **args) -> None:
+        return None
 
 
 _NULL_SPAN = _NullSpan()
@@ -70,6 +93,13 @@ class NullTracer:
     def instant(self, name: str, **args) -> None:
         return None
 
+    def complete(self, name: str, t0_ns: int, t1_ns: int,
+                 tid: int | None = None, **args) -> None:
+        return None
+
+    def wave(self, seq: int):
+        return _NULL_SPAN
+
     def flush(self) -> None:
         return None
 
@@ -79,17 +109,84 @@ class NullTracer:
 
 NULL = NullTracer()
 
+# per thread: the parts open, innermost last, and the wave bound
+_OPEN = threading.local()
+
+
+class _Wave:
+    __slots__ = ("seq", "prev")
+
+    def __init__(self, seq: int):
+        self.seq = seq
+
+    def __enter__(self):
+        self.prev = getattr(_OPEN, "wave", None)
+        _OPEN.wave = self.seq
+        return self
+
+    def __exit__(self, *exc):
+        _OPEN.wave = self.prev
+        return False
+
+
+def current_part() -> "_Part | None":
+    """The innermost part open on the calling thread, or None."""
+    stack = getattr(_OPEN, "parts", None)
+    return stack[-1] if stack else None
+
+
+class _Part:
+    __slots__ = ("span", "name", "labels", "t0")
+
+    def __init__(self, span: "_Span", name: str, labels: tuple):
+        self.span = span
+        self.name = name
+        self.labels = labels
+
+    def __enter__(self):
+        stack = getattr(_OPEN, "parts", None)
+        if stack is None:
+            stack = _OPEN.parts = []
+        stack.append(self)
+        self.t0 = clock.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = clock.monotonic_ns()
+        _OPEN.parts.pop()
+        self.span.add_part(self.name, self.t0, t1, *self.labels)
+        return False
+
 
 class _Span:
-    __slots__ = ("tracer", "name", "args", "t0", "annotation")
+    __slots__ = ("tracer", "name", "args", "t0", "annotation", "parts")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer = tracer
         self.name = name
         self.args = args
         self.annotation = None
+        self.parts = None
+
+    def part(self, name: str, *labels) -> _Part:
+        """Context manager timing a part of this (open) span."""
+        return _Part(self, name, labels)
+
+    def add_part(self, name: str, t0_ns: int, t1_ns: int, *labels) -> None:
+        """Record a part that ran from ``t0_ns`` to ``t1_ns``."""
+        if self.parts is None:
+            self.parts = []
+        self.parts.append([name, (t0_ns - self.t0) // 1000,
+                           max(t1_ns - t0_ns, 0) // 1000, *labels])
+
+    def set(self, **args) -> None:
+        """Add args known only once the span is open (a ticket)."""
+        self.args.update(args)
 
     def __enter__(self):
+        wave = getattr(_OPEN, "wave", None)
+        if wave is not None:
+            self.args.setdefault("wave", wave)
         self.t0 = clock.monotonic_ns()
         ann = self.tracer._annotation
         if ann is not None:
@@ -101,12 +198,10 @@ class _Span:
         if self.annotation is not None:
             self.annotation.__exit__(*exc)
         t1 = clock.monotonic_ns()
-        self.tracer._emit({
-            "ph": "X", "name": self.name, "cat": "wave",
-            "ts": self.t0 // 1000, "dur": max((t1 - self.t0) // 1000, 1),
-            "pid": self.tracer.pid, "tid": threading.get_ident() & 0xFFFF,
-            "args": self.args,
-        })
+        if self.parts is not None:
+            self.args["parts"] = self.parts
+        self.tracer._complete(self.name, self.t0, t1, current_tid(),
+                              self.args)
         return False
 
 
@@ -133,13 +228,34 @@ class Tracer:
         """Context manager timing one pipeline stage."""
         return _Span(self, name, args)
 
+    def wave(self, seq: int) -> _Wave:
+        """Context manager: spans the calling thread opens inside it
+        carry ``wave=seq`` (unless given one), however deep the call
+        that opens them (the store's ``wal_commit``)."""
+        return _Wave(seq)
+
+    def complete(self, name: str, t0_ns: int, t1_ns: int,
+                 tid: int | None = None, **args) -> None:
+        """Emit a span that ran from ``t0_ns`` to ``t1_ns`` (monotonic
+        ns), after the fact, on ``tid`` (default: the calling thread)."""
+        self._complete(name, t0_ns, t1_ns,
+                       current_tid() if tid is None else tid, args)
+
+    def _complete(self, name: str, t0_ns: int, t1_ns: int, tid: int,
+                  args: dict) -> None:
+        self._emit({
+            "ph": "X", "name": name, "cat": "wave",
+            "ts": t0_ns // 1000, "dur": max((t1_ns - t0_ns) // 1000, 1),
+            "pid": self.pid, "tid": tid, "args": args,
+        })
+
     def instant(self, name: str, **args) -> None:
         """A point event (failure paths: restarts, stragglers, torn
         commits) carrying stream/wave identity in ``args``."""
         self._emit({
             "ph": "i", "name": name, "cat": "event", "s": "t",
             "ts": clock.monotonic_ns() // 1000,
-            "pid": self.pid, "tid": threading.get_ident() & 0xFFFF,
+            "pid": self.pid, "tid": current_tid(),
             "args": args,
         })
 

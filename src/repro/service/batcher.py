@@ -142,12 +142,18 @@ class RoundBatcher:
 
         from repro.kernels import template
         launches_before = template.launch_count()
+        plan_hits = {True: 0, False: 0, None: 0}
         results: list[tuple[CacheEntry, int, SumsState]] = []
-        with obs.span("launch", items=len(unique), groups=len(groups)):
+        with obs.span("launch", items=len(unique),
+                      groups=len(groups)) as span:
             self.faults.check("launch")
             for group_key in sorted(groups):
-                results.extend(self._launch_group(groups[group_key]))
+                out, hit = self._launch_group(groups[group_key], span)
+                results.extend(out)
+                plan_hits[hit] += 1
         obs.m["launches"].inc(template.launch_count() - launches_before)
+        obs.m["plan_cache_hits"].inc(plan_hits[True])
+        obs.m["plan_cache_misses"].inc(plan_hits[False])
         return InFlightWave(results=results, n_items=len(unique))
 
     def deposit(self, wave: InFlightWave) -> int:
@@ -174,14 +180,16 @@ class RoundBatcher:
                 import jax
                 jax.block_until_ready([sums.s1 for _, _, sums
                                        in wave.results])
-        with obs.span("transfer", items=wave.n_items):
+        with obs.span("transfer", items=wave.n_items) as span:
             self.faults.check("transfer")
-            deposits = [
-                (entry, round_index,
-                 SumsState(s1=np.asarray(sums.s1, np.float32),
-                           s2=np.asarray(sums.s2, np.float32),
-                           n=np.float32(np.asarray(sums.n))))
-                for entry, round_index, sums in wave.results]
+            with span.part("copies"):
+                deposits = [
+                    (entry, round_index,
+                     SumsState(s1=np.asarray(sums.s1, np.float32),
+                               s2=np.asarray(sums.s2, np.float32),
+                               n=np.float32(np.asarray(sums.n))))
+                    for entry, round_index, sums in wave.results]
+            obs.m["d2h_copies"].inc(3 * len(deposits))
             if (self.faults.enabled and deposits
                     and self.faults.fire("transfer_nan")):
                 # poison the wave's first deposit: the cache's finite
@@ -215,8 +223,11 @@ class RoundBatcher:
                                start=rounds[0], count=len(rounds)))
         return spans
 
-    def _launch_group(self, spans: list[_Span]):
-        """One fused multi-round evaluation of same-count spans."""
+    def _launch_group(self, spans: list[_Span], trace_span):
+        """One fused multi-round evaluation of same-count spans; its
+        plan build, dispatch and unpack are parts of ``trace_span``.
+        Returns the group's ``(entry, round, sums)`` and whether its
+        fusion plan was cached (None when nothing was fused)."""
         n = self.cache.round_samples
         count = spans[0].count
         sampler = spans[0].sampler
@@ -232,6 +243,7 @@ class RoundBatcher:
         degraded = [sp for sp in spans if sp.entry.degraded]
 
         fused: dict[int, tuple] = {}
+        hit = None
         if self.use_kernel and healthy:
             entries = [sp.entry for sp in healthy]
             fn_offsets = [e.fn_offset for e in entries]
@@ -239,16 +251,19 @@ class RoundBatcher:
                 families=tuple(e.family for e in entries))
             from repro.kernels.mc_eval import multi
             self.faults.check("device_error")
-            plan = self._plan_for(entries, sampler, spec, fn_offsets)
+            with trace_span.part("build"):
+                plan, hit = self._plan_for(entries, sampler, spec,
+                                           fn_offsets)
             start_rounds = {i: sp.start for i, sp in enumerate(healthy)}
             if self.mesh is not None:
                 fused = multi.sharded_eval_plan_rounds(
                     plan, n, count, self.key, self.mesh,
                     start_rounds=start_rounds, fn_axis=self.fn_axis,
-                    sample_axes=self.sample_axes)
+                    sample_axes=self.sample_axes, part=trace_span.part)
             else:
                 fused = multi.eval_plan_rounds(
-                    plan, n, count, self.key, start_rounds=start_rounds)
+                    plan, n, count, self.key, start_rounds=start_rounds,
+                    part=trace_span.part)
 
         out = []
         for idx, sp in enumerate(healthy):
@@ -259,7 +274,7 @@ class RoundBatcher:
             out.extend(self._chunked_rounds(sp, count, n, sampler))
         for sp in degraded:
             out.extend(self._chunked_rounds(sp, count, n, sampler))
-        return out
+        return out, hit
 
     def _chunked_rounds(self, sp: _Span, count: int, n: int, sampler: str):
         """Chunked fallback: one counter-addressed eval per round."""
@@ -288,7 +303,8 @@ class RoundBatcher:
 
     def _plan_for(self, entries: list[CacheEntry], sampler: str, spec,
                   fn_offsets):
-        """LRU-cached fusion plan for this exact entry set.
+        """LRU-cached fusion plan for this exact entry set, and whether
+        it was cached.
 
         The plan holds packed per-entry operands, so the cache key is the
         entry identity tuple; eviction is least-recently-used (a full
@@ -301,9 +317,9 @@ class RoundBatcher:
         plan = self._plans.get(plan_key)
         if plan is not None:
             self._plans.move_to_end(plan_key)
-            return plan
+            return plan, True
         plan = multi.plan_spec(spec, sampler=sampler, fn_offsets=fn_offsets)
         self._plans[plan_key] = plan
         while len(self._plans) > self.plan_cache_size:
             self._plans.popitem(last=False)
-        return plan
+        return plan, False

@@ -73,6 +73,7 @@ from repro.core import adaptive
 from repro.core import rng as rng_lib
 from repro.obs import Observability
 from repro.obs import clock as _clock
+from repro.obs.trace import current_tid
 from repro.service.api import (Backpressure, IntegrationRequest,
                                IntegrationResult, RequestFailed,
                                SweepRequest, SweepResult)
@@ -95,6 +96,41 @@ def _wave_streams(items: Sequence[WorkItem]) -> list[str]:
         if sid not in seen:
             seen.append(sid)
     return seen
+
+
+class _Acquire:
+    """``with _Acquire(lock, timer):`` holds ``lock``, the wait for it
+    timed by ``timer`` (a trace span or part, closed once the lock is
+    held; the shared no-op when tracing is off)."""
+
+    __slots__ = ("lock", "timer")
+
+    def __init__(self, lock, timer):
+        self.lock = lock
+        self.timer = timer
+
+    def __enter__(self):
+        with self.timer:
+            self.lock.acquire()
+        return self
+
+    def __exit__(self, *exc):
+        self.lock.release()
+        return False
+
+
+@dataclasses.dataclass
+class _TicketTrace:
+    """What a ticket's ``request`` span reports (tracing on only):
+    monotonic ns of its submit, admission and first launch, the client
+    thread's tid, and the waves and rounds that carried its streams."""
+
+    tid: int
+    submit_ns: int
+    admit_ns: int
+    launch_ns: int | None = None
+    waves: int = 0
+    rounds: int = 0
 
 
 @dataclasses.dataclass
@@ -157,6 +193,7 @@ class _Pending:
     new_rounds_scheduled: bool = False
     sweep: _SweepInfo | None = None
     deadline: Deadline | None = None
+    trace: _TicketTrace | None = None
 
 
 class IntegrationEngine:
@@ -183,6 +220,7 @@ class IntegrationEngine:
                  adapt_max_epochs: int = 3,
                  adapt_rounds_per_epoch: int = 2):
         # telemetry first: every layer below receives the same bundle
+        self._own_obs = obs is None
         self.obs = obs if obs is not None else Observability.disabled()
         self.seed = int(seed)
         self.key = rng_lib.fold_key(self.seed, 0)
@@ -306,18 +344,22 @@ class IntegrationEngine:
         # map would compete for the packed row; see docs/adaptive.md)
         adapt = bool(getattr(request, "adaptive", False)
                      and request.target_stderr is not None)
-        canon_fams = []
-        for fam in request.families:
-            canon = canonical_family(fam)
-            chash = f"{family_hash(canon, canonicalize=False)}:{request.sampler}"
-            if adapt and not canon.swept:
-                with self._lock:
-                    ast = self._adaptive_state(chash, canon, request.sampler)
-                canon_fams.append((ast.chash, ast.family))
-            else:
-                canon_fams.append((chash, canon))
-        return self._submit_canonical(request, canon_fams, block=block,
-                                      timeout=timeout)
+        with self.obs.span("submit", n_fn=sum(f.n_fn for f in
+                                              request.families)) as span:
+            canon_fams = []
+            for fam in request.families:
+                canon = canonical_family(fam)
+                chash = (f"{family_hash(canon, canonicalize=False)}:"
+                         f"{request.sampler}")
+                if adapt and not canon.swept:
+                    with _Acquire(self._lock, span.part("lock_wait")):
+                        ast = self._adaptive_state(chash, canon,
+                                                   request.sampler, span)
+                    canon_fams.append((ast.chash, ast.family))
+                else:
+                    canon_fams.append((chash, canon))
+            return self._submit_canonical(request, canon_fams, block=block,
+                                          timeout=timeout, span=span)
 
     def submit_sweep(self, request: SweepRequest, *, block: bool = True,
                      timeout: float | None = None) -> int:
@@ -335,6 +377,12 @@ class IntegrationEngine:
         submit with the nearest supported combo named, instead of
         silently falling back for 10^5 points.
         """
+        with self.obs.span("submit") as span:
+            return self._submit_sweep(request, block=block, timeout=timeout,
+                                      span=span)
+
+    def _submit_sweep(self, request: SweepRequest, *, block: bool,
+                      timeout: float | None, span) -> int:
         with self.obs.span("sweep_plan", template=request.template.name,
                            axes=len(request.grid)):
             fams, shape, axis_names = sweep_slices(
@@ -354,6 +402,7 @@ class IntegrationEngine:
                 (f"{family_hash(f, canonicalize=False)}:{request.sampler}", f)
                 for f in fams]
         n_points = int(np.prod(shape))
+        span.set(n_fn=n_points)
         shared = sum(1 for chash, f in canon_fams
                      if self.cache.get(chash, f) is not None)
         self.obs.m["sweep_submitted"].inc()
@@ -368,13 +417,15 @@ class IntegrationEngine:
                            slice_sizes=tuple(f.n_fn for f in fams),
                            slice_names=tuple(f.name for f in fams))
         return self._submit_canonical(request, canon_fams, block=block,
-                                      timeout=timeout, sweep=sweep)
+                                      timeout=timeout, sweep=sweep,
+                                      span=span)
 
     def _submit_canonical(self, request, canon_fams, *, block: bool,
-                          timeout: float | None,
+                          timeout: float | None, span,
                           sweep: _SweepInfo | None = None) -> int:
         """Shared tail of :meth:`submit`/:meth:`submit_sweep`: cache-hit
-        peek, pending-table admission, allocation."""
+        peek, pending-table admission, allocation.  ``span`` is the
+        ``submit`` span: its ``lock_wait`` parts time the engine lock."""
         # hit path needs no allocation: all entries must already exist
         # (a persisted stream from a previous process counts — passing
         # the family lets the cache rehydrate it, so a warm *restart*
@@ -384,17 +435,19 @@ class IntegrationEngine:
             req = request
             if all(self.cache.meets(e, target_stderr=req.target_stderr,
                                     n_samples=req.n_samples) for e in peek):
-                with self._lock:
+                with _Acquire(self._lock, span.part("lock_wait")):
                     ticket = self._new_ticket()
                     pend = _Pending(ticket=ticket, request=request,
                                     entries=list(peek),
-                                    event=threading.Event(), sweep=sweep)
+                                    event=threading.Event(), sweep=sweep,
+                                    trace=self._ticket_trace(span))
+                    span.set(ticket=ticket, cache="hit")
                     self.stats.cache_hits += 1
                     self.obs.m["cache_requests"].inc(outcome="hit")
                     self._finish(pend, served_from_cache=True)
                 return ticket
 
-        with self._lock:
+        with _Acquire(self._lock, span.part("lock_wait")):
             while len(self._pending) >= self.max_pending:
                 if not block:
                     raise Backpressure(
@@ -409,17 +462,27 @@ class IntegrationEngine:
             pend = _Pending(ticket=ticket, request=request, entries=entries,
                             event=threading.Event(), sweep=sweep,
                             deadline=(None if budget is None
-                                      else Deadline(budget)))
+                                      else Deadline(budget)),
+                            trace=self._ticket_trace(span))
             if self._meets(pend):     # became satisfiable while we waited
+                span.set(ticket=ticket, cache="hit")
                 self.stats.cache_hits += 1
                 self.obs.m["cache_requests"].inc(outcome="hit")
                 self._finish(pend, served_from_cache=True)
                 return ticket
+            span.set(ticket=ticket, cache="miss")
             self.obs.m["cache_requests"].inc(outcome="miss")
             self._pending[ticket] = pend
             self.obs.m["pending"].set(len(self._pending))
             self._work_cv.notify_all()
         return ticket
+
+    def _ticket_trace(self, span) -> _TicketTrace | None:
+        """A ticket's trace record, admitted now (tracing on only)."""
+        if not self.obs.tracing:
+            return None
+        return _TicketTrace(tid=current_tid(), submit_ns=span.t0,
+                            admit_ns=_clock.monotonic_ns())
 
     def _new_ticket(self) -> int:
         ticket = self._next_ticket
@@ -552,18 +615,20 @@ class IntegrationEngine:
         driver's wave), False when the pending table made no progress
         (empty or already satisfiable).
         """
-        with self._lock:
-            with self.obs.span("plan", pending=len(self._pending)):
-                items = self._plan_wave()
+        with _Acquire(self._lock, self.obs.span("lock_wait",
+                                                wave=self._wave_seq)):
+            seq = self._wave_seq
+            items, riders = self._plan_traced(seq)
             if not items:
-                self._complete_ready()
+                with self.obs.span("complete", wave=seq):
+                    self._complete_ready()
                 if self._awaiting_other_driver_locked():
                     # every remaining round is in another driver's wave;
                     # wait for a deposit instead of claiming deadlock
-                    self._deposit_cv.wait(timeout=1.0)
+                    with self.obs.span("idle", wave=seq):
+                        self._deposit_cv.wait(timeout=1.0)
                     return True
                 return False
-            seq = self._wave_seq
             self._wave_seq += 1
 
         def wave(attempt: int) -> int:
@@ -577,11 +642,13 @@ class IntegrationEngine:
 
         t0 = _clock.monotonic()
         stragglers_before = self.watchdog.straggler_count
+        self._stamp_launch(riders)
         try:
-            executed = run_with_policy(
-                wave, self.retry, stage="wave", counter=seq,
-                deadline=self._wave_deadline(items),
-                on_retry=self._restart_hook("wave_restart", seq, items))
+            with self.obs.wave(seq):
+                executed = run_with_policy(
+                    wave, self.retry, stage="wave", counter=seq,
+                    deadline=self._wave_deadline(items, seq),
+                    on_retry=self._restart_hook("wave_restart", seq, items))
         except (RetryExhausted, DeadlineExceeded) as exc:
             # the wave is permanently lost: complete its tickets with a
             # structured failure, then surface the error to this
@@ -597,12 +664,47 @@ class IntegrationEngine:
         self._note_stragglers(stragglers_before, seq, items)
         self.obs.m["waves"].inc()
         self.obs.m["wave_seconds"].observe(_clock.monotonic() - t0)
-        with self._lock:
-            self._retire_items(items)
-            self.stats.waves += 1
-            self.stats.items_executed += executed
-            self._complete_ready()
+        self._complete_wave(items, executed, seq)
         return True
+
+    def _complete_wave(self, items: Sequence[WorkItem], executed: int,
+                       seq: int) -> None:
+        """Retire a deposited wave and finish the requests it met."""
+        with _Acquire(self._lock, self.obs.span("lock_wait", wave=seq)):
+            with self.obs.span("complete", wave=seq):
+                self._retire_items(items)
+                self.stats.waves += 1
+                self.stats.items_executed += executed
+                self._complete_ready()
+
+    def _plan_traced(self, seq: int) -> tuple[list[WorkItem], list[_Pending]]:
+        """Plan wave ``seq`` inside its ``plan`` span (caller holds the
+        lock; a refit's journal writes carry the wave too).  With
+        tracing on, also returns the wave's riders, whose tickets the
+        span carries and whose trace records count it."""
+        with self.obs.wave(seq), self.obs.span(
+                "plan", pending=len(self._pending)) as span:
+            items = self._plan_wave(span)
+            riders: list[_Pending] = []
+            if items and self.obs.tracing:
+                per_stream = collections.Counter(it.chash for it in items)
+                for pend in self._pending.values():
+                    rounds = sum(per_stream[e.chash] for e in pend.entries)
+                    if rounds and pend.trace is not None:
+                        pend.trace.waves += 1
+                        pend.trace.rounds += rounds
+                        riders.append(pend)
+                span.set(tickets=[p.ticket for p in riders])
+        return items, riders
+
+    @staticmethod
+    def _stamp_launch(riders: Sequence[_Pending]) -> None:
+        """Note the first launch that carries each rider's rounds."""
+        if riders:
+            now = _clock.monotonic_ns()
+            for pend in riders:
+                if pend.trace.launch_ns is None:
+                    pend.trace.launch_ns = now
 
     # -- telemetry hooks ------------------------------------------------------
     def _restart_hook(self, kind: str, seq: int,
@@ -640,11 +742,13 @@ class IntegrationEngine:
                    for e in p.entries)
 
     # -- failure surfacing ----------------------------------------------------
-    def _wave_deadline(self, items: Sequence[WorkItem]) -> Deadline | None:
-        """Tightest remaining per-request deadline riding this wave, as
-        a fresh budget for the retry loop (None when no rider has one)."""
+    def _wave_deadline(self, items: Sequence[WorkItem],
+                       seq: int) -> Deadline | None:
+        """Tightest remaining per-request deadline riding wave ``seq``,
+        as a fresh budget for the retry loop (None when no rider has
+        one)."""
         streams = {it.chash for it in items}
-        with self._lock:
+        with _Acquire(self._lock, self.obs.span("lock_wait", wave=seq)):
             remains = [p.deadline.remaining()
                        for p in self._pending.values()
                        if p.deadline is not None
@@ -698,7 +802,20 @@ class IntegrationEngine:
         self.obs.event("request_failed", ticket=pend.ticket, reason=reason,
                        stage=stage, streams=[c[:16]
                                              for c in pend.result.stream_ids])
+        self._trace_request(pend, failed=reason)
         pend.event.set()
+
+    def _trace_request(self, pend: _Pending, **args) -> None:
+        """The ``request`` span of a finished ticket, submit to now, on
+        the thread that submitted it (tracing on only)."""
+        tr = pend.trace
+        if tr is None:
+            return
+        queue_us = (None if tr.launch_ns is None
+                    else (tr.launch_ns - tr.admit_ns) // 1000)
+        self.obs.complete("request", tr.submit_ns, _clock.monotonic_ns(),
+                          tr.tid, ticket=pend.ticket, queue_us=queue_us,
+                          waves=tr.waves, rounds=tr.rounds, **args)
 
     # -- importance-grid adaptation -------------------------------------------
     def _pilot_key(self, base_chash: str, epoch: int) -> tuple:
@@ -712,10 +829,10 @@ class IntegrationEngine:
         sid = zlib.crc32(f"adapt:{base_chash}:{int(epoch)}".encode())
         return rng_lib.fold_key(self.seed, sid)
 
-    def _adaptive_state(self, base_chash: str, canon,
-                        sampler: str) -> _AdaptiveState:
+    def _adaptive_state(self, base_chash: str, canon, sampler: str,
+                        span) -> _AdaptiveState:
         """Active importance-grid state for one base stream (caller
-        holds the lock).
+        holds the lock); a fresh fit is the ``pilot`` part of ``span``.
 
         Resume first: when the WAL/snapshot carries an epoch chain
         rooted at ``base_chash`` the planner adopts its tip — recorded
@@ -736,12 +853,14 @@ class IntegrationEngine:
                 epoch=tip.epoch, edges=np.asarray(tip.edges),
                 chash=tip.chash, family=fam)
         else:
-            edges = adaptive.initial_edges(np.asarray(canon.domains),
-                                           self.adapt_bins)
-            weights = adaptive.pilot_weights(
-                canon, edges, self._pilot_key(base_chash, 1),
-                self.adapt_pilot_samples)
-            edges = adaptive.refine_edges(edges, weights)
+            with span.part("pilot"):
+                edges = adaptive.initial_edges(np.asarray(canon.domains),
+                                               self.adapt_bins)
+                weights = adaptive.pilot_weights(
+                    canon, edges, self._pilot_key(base_chash, 1),
+                    self.adapt_pilot_samples)
+                edges = adaptive.refine_edges(edges, weights)
+            self.obs.m["grid_pilots"].inc()
             fam = canon.adapted(edges, epoch=1)
             chash = f"{family_hash(fam, canonicalize=False)}:{sampler}"
             self.cache.register_grid(chash, parent=base_chash, epoch=1,
@@ -753,9 +872,10 @@ class IntegrationEngine:
         self._adaptive[base_chash] = ast
         return ast
 
-    def _maybe_refit_locked(self) -> None:
+    def _maybe_refit_locked(self, span) -> None:
         """Open the next grid epoch for adapted streams still chasing
-        their stderr target (caller holds the lock).
+        their stderr target (caller holds the lock); each pilot and
+        refinement is a ``refit`` part of the ``plan`` span ``span``.
 
         Every trigger input is durable or deterministic — the current
         epoch stream's ``rounds_done`` (WAL-recovered), the rider's
@@ -788,11 +908,13 @@ class IntegrationEngine:
                                 n_samples=None):
                 continue    # met — _complete_ready finishes the riders
             epoch = ast.epoch + 1
-            weights = adaptive.pilot_weights(
-                ast.base_family, ast.edges,
-                self._pilot_key(ast.base_chash, epoch),
-                self.adapt_pilot_samples)
-            edges = adaptive.refine_edges(ast.edges, weights)
+            with span.part("refit"):
+                weights = adaptive.pilot_weights(
+                    ast.base_family, ast.edges,
+                    self._pilot_key(ast.base_chash, epoch),
+                    self.adapt_pilot_samples)
+                edges = adaptive.refine_edges(ast.edges, weights)
+            self.obs.m["grid_pilots"].inc()
             if np.array_equal(edges, ast.edges):
                 ast.frozen = True    # a resume re-derives this verdict
                 continue
@@ -812,7 +934,7 @@ class IntegrationEngine:
             ast.chash, ast.edges, ast.epoch, ast.family = \
                 chash, edges, epoch, fam
 
-    def _plan_wave(self) -> list[WorkItem]:
+    def _plan_wave(self, span) -> list[WorkItem]:
         """Assign the wave's round budget fairly across pending requests.
 
         Needs are computed beyond each stream's fold frontier plus rounds
@@ -822,10 +944,11 @@ class IntegrationEngine:
         every pending request makes progress every wave: heavy precision
         asks cannot monopolize the budget.  Scheduled rounds are
         registered in-flight; callers retire them after deposit (or on
-        permanent failure).  Caller must hold the engine lock.
+        permanent failure).  Caller must hold the engine lock; ``span``
+        is the ``plan`` span.
         """
         if self._adaptive:
-            self._maybe_refit_locked()
+            self._maybe_refit_locked(span)
         info: dict[str, dict] = {}
         order: list[str] = []
         for pend in self._pending.values():
@@ -969,6 +1092,7 @@ class IntegrationEngine:
         self.obs.m["served"].inc()
         if served_from_cache:
             self.obs.m["warm_zero_launch"].inc()
+        self._trace_request(pend)
         pend.event.set()
 
     # -- background worker ----------------------------------------------------
@@ -1028,6 +1152,8 @@ class IntegrationEngine:
         finally:
             if self.store is not None:
                 self.store.close()
+            if self._own_obs:
+                self.obs.close()
 
     def __enter__(self) -> "IntegrationEngine":
         return self
@@ -1055,8 +1181,10 @@ class IntegrationEngine:
                     self.store.heartbeat()   # idle engines keep the lease
                 self.faults.check("worker_crash")
                 with self._lock:
-                    while not self._pending and not self._stop:
-                        self._work_cv.wait(timeout=0.5)
+                    if not self._pending and not self._stop:
+                        with self.obs.span("idle", wave=self._wave_seq):
+                            while not self._pending and not self._stop:
+                                self._work_cv.wait(timeout=0.5)
                     if self._stop:
                         return
                 try:
@@ -1093,25 +1221,27 @@ class IntegrationEngine:
                 # wave boundary with nothing salvageable in flight: the
                 # only spot where an injected worker death is loss-free
                 self.faults.check("worker_crash")
-            with self._lock:
-                while (not self._pending and inflight is None
-                       and not self._stop):
-                    self._work_cv.wait(timeout=0.5)
+            with _Acquire(self._lock, self.obs.span("lock_wait",
+                                                    wave=self._wave_seq)):
+                seq = self._wave_seq
+                if not self._pending and inflight is None and not self._stop:
+                    with self.obs.span("idle", wave=seq):
+                        while (not self._pending and inflight is None
+                               and not self._stop):
+                            self._work_cv.wait(timeout=0.5)
                 if self._stop and inflight is None:
                     return
-                if self._stop:
-                    items = []
-                else:
-                    with self.obs.span("plan", pending=len(self._pending)):
-                        items = self._plan_wave()
+                items, riders = (([], []) if self._stop
+                                 else self._plan_traced(seq))
                 if not items and inflight is None:
-                    self._complete_ready()
+                    with self.obs.span("complete", wave=seq):
+                        self._complete_ready()
                     if self._pending:
                         # nothing plannable here, rounds owed to another
                         # driver's wave: wait for its deposit
-                        self._deposit_cv.wait(timeout=0.5)
+                        with self.obs.span("idle", wave=seq):
+                            self._deposit_cv.wait(timeout=0.5)
                     continue
-                seq = self._wave_seq
                 if items:
                     self._wave_seq += 1
 
@@ -1128,12 +1258,14 @@ class IntegrationEngine:
                         return self.batcher.launch(_items)
 
                 stragglers_before = self.watchdog.straggler_count
+                self._stamp_launch(riders)
                 try:
-                    handle = run_with_policy(
-                        launch, self.retry, stage="launch", counter=seq,
-                        deadline=self._wave_deadline(items),
-                        on_retry=self._restart_hook(
-                            "wave_restart", seq, items))
+                    with self.obs.wave(seq):
+                        handle = run_with_policy(
+                            launch, self.retry, stage="launch", counter=seq,
+                            deadline=self._wave_deadline(items, seq),
+                            on_retry=self._restart_hook(
+                                "wave_restart", seq, items))
                 except (RetryExhausted, DeadlineExceeded) as exc:
                     # permanent: complete the riders as RequestFailed
                     # and keep serving — the sibling wave deposits below
@@ -1187,10 +1319,12 @@ class IntegrationEngine:
 
         stragglers_before = self.watchdog.straggler_count
         try:
-            executed = run_with_policy(
-                attempt, self.retry, stage="deposit", counter=seq,
-                deadline=self._wave_deadline(items),
-                on_retry=self._restart_hook("deposit_retry", seq, items))
+            with self.obs.wave(seq):
+                executed = run_with_policy(
+                    attempt, self.retry, stage="deposit", counter=seq,
+                    deadline=self._wave_deadline(items, seq),
+                    on_retry=self._restart_hook("deposit_retry", seq,
+                                                items))
         except (RetryExhausted, DeadlineExceeded) as exc:
             # permanent loss of this wave only: fail its riders and let
             # the worker keep serving everything else
@@ -1207,8 +1341,4 @@ class IntegrationEngine:
         if t_launch is not None:
             self.obs.m["wave_seconds"].observe(
                 _clock.monotonic() - t_launch)
-        with self._lock:
-            self._retire_items(items)
-            self.stats.waves += 1
-            self.stats.items_executed += executed
-            self._complete_ready()
+        self._complete_wave(items, executed, seq)

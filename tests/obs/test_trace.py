@@ -185,3 +185,110 @@ class TestClockShim:
         b = clock.monotonic()
         assert b >= a
         assert clock.monotonic_ns() > 0
+
+
+class TestAfterTheFactAndParts:
+    def test_complete_emits_span_on_given_tid(self, fake_clock):
+        events = []
+        tracer = Tracer(events.append)
+        tracer.complete("request", 100_000_000_000, 100_002_500_000,
+                        tid=77, ticket=3, queue_us=12)
+        (ev,) = events
+        assert (ev["ph"], ev["name"], ev["tid"]) == ("X", "request", 77)
+        assert (ev["ts"], ev["dur"]) == (100_000_000, 2500)
+        assert ev["args"] == {"ticket": 3, "queue_us": 12}
+
+    def test_null_tracer_complete_is_a_noop(self):
+        assert NULL.complete("request", 0, 10, tid=1, ticket=0) is None
+
+    def test_parts_lie_inside_their_span(self, fake_clock):
+        events = []
+        tracer = Tracer(events.append)
+        with tracer.span("launch") as span:
+            fake_clock(0.001)
+            with span.part("build"):
+                fake_clock(0.0025)
+            with span.part("dispatch", "mc_eval_fused_mc_d3f16c54_r2"):
+                fake_clock(0.004)
+            fake_clock(0.0005)
+        (ev,) = events
+        assert ev["args"]["parts"] == [
+            ["build", 1000, 2500],
+            ["dispatch", 3500, 4000, "mc_eval_fused_mc_d3f16c54_r2"]]
+        for _, off, dur, *_ in ev["args"]["parts"]:
+            assert 0 <= off and off + dur <= ev["dur"]
+
+    def test_current_part_is_the_innermost_open_one(self, fake_clock):
+        from repro.obs.trace import current_part
+        tracer = Tracer(lambda ev: None)
+        assert current_part() is None
+        with tracer.span("launch") as span:
+            with span.part("dispatch", "b") as outer:
+                assert current_part() is outer
+                with span.part("unpack") as inner:
+                    assert current_part() is inner
+                assert current_part() is outer
+        assert current_part() is None
+
+    def test_wave_binding_reaches_nested_spans(self, fake_clock):
+        events = []
+        tracer = Tracer(events.append)
+        with tracer.wave(7):
+            with tracer.span("deposit"):
+                with tracer.span("wal_commit", bytes=10):
+                    pass
+            with tracer.span("plan", wave=8):
+                pass
+        with tracer.span("launch"):
+            pass
+        assert [e["args"].get("wave") for e in events] == [7, 7, 8, None]
+
+    def test_disabled_bundle_emits_nothing_and_records_no_parts(
+            self, fake_clock):
+        from repro.obs import Observability
+        obs = Observability.disabled()
+        span = obs.span("launch", items=3)
+        assert span is NULL.span("plan")       # the shared no-op
+        with span:
+            with span.part("build") as part:
+                assert part is span            # no part object, no clock
+            span.set(ticket=1)
+        with obs.wave(3):
+            obs.complete("request", 0, 10)
+        assert not hasattr(span, "parts")
+        obs.close()
+
+
+class TestCompileListener:
+    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration",
+              "/jax/core/compile/backend_compile_duration")
+
+    def test_counts_phases_and_marks_the_open_part(self, fake_clock):
+        import jax.monitoring
+        from repro.obs import Observability
+        events = []
+        obs = Observability.enabled(sinks=(events.append,))
+        try:
+            with obs.span("launch") as span:
+                with span.part("dispatch", "mc_eval_fused_mc_d3f16c54_r2"):
+                    fake_clock(0.003)
+                    for ev, dur in zip(self.EVENTS, (0.001, 0.0005, 0.002)):
+                        jax.monitoring.record_event_duration_secs(ev, dur)
+            jax.monitoring.record_event_duration_secs(self.EVENTS[2], 0.25)
+        finally:
+            obs.close()
+        assert obs.m["backend_compiles"].value() == 2
+        hist = obs.m["compile_seconds"]
+        assert hist.count(phase="trace") == hist.count(phase="lower") == 1
+        assert hist.sum(phase="backend") == pytest.approx(0.252)
+        (launch,) = events
+        compiles = [p for p in launch["args"]["parts"] if p[0] == "compile"]
+        assert [p[3:] for p in compiles] == [
+            ["mc_eval_fused_mc_d3f16c54_r2", ph]
+            for ph in ("trace", "lower", "backend")]
+        assert [p[2] for p in compiles] == [1000, 500, 2000]
+        # closed: the listener is gone, so later compiles are not counted
+        jax.monitoring.record_event_duration_secs(self.EVENTS[2], 0.1)
+        assert obs.m["backend_compiles"].value() == 2
+        obs.close()                                   # idempotent

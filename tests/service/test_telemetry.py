@@ -7,6 +7,7 @@ exercise the primitives in isolation; here the assertion is that the
 *wiring* through plan/launch/deposit is complete and honest.
 """
 
+import numpy as np
 import pytest
 
 from repro.core import gaussian_family, harmonic_family
@@ -190,3 +191,133 @@ class TestFailurePathEvents:
         assert evs[0]["args"]["duration"] > 0
         assert res.stream_ids[0][:16] in evs[0]["args"]["streams"]
         assert obs.m["stragglers"].value() == dog.straggler_count
+
+
+# spans the request path and the worker's loop add beside the stages
+NEW_SPANS = ("submit", "lock_wait", "complete", "idle", "request")
+
+
+def _spans(events, name=None):
+    return [e for e in events if e.get("ph") == "X"
+            and (name is None or e["name"] == name)]
+
+
+@pytest.fixture
+def served_adaptive(make_engine, obs, events, tmp_path):
+    """One adaptive request served by the pipelined worker thread, with a
+    durable store: (engine, result, the trace's spans)."""
+    engine = make_engine(state_dir=str(tmp_path), obs=obs,
+                         adapt_rounds_per_epoch=1, adapt_max_epochs=3,
+                         adapt_pilot_samples=1024)
+    engine.start()
+    res = IntegrationClient(engine).integrate(
+        [gaussian_family(2, 2, sigma=np.asarray([0.15, 0.25]))],
+        target_stderr=5e-4, adaptive=True)
+    engine.stop()
+    return engine, res, _spans(events)
+
+
+class TestRequestPathTracing:
+    def test_adaptive_request_yields_submit_lock_complete_request(
+            self, served_adaptive, obs):
+        engine, res, spans = served_adaptive
+        names = {s["name"] for s in spans}
+        assert {"submit", "lock_wait", "complete", "request"} <= names
+        (submit,) = _spans(spans, "submit")
+        assert submit["args"]["ticket"] == res.ticket
+        assert submit["args"]["cache"] == "miss"
+        assert submit["args"]["n_fn"] == 2
+        parts = {p[0] for p in submit["args"]["parts"]}
+        assert {"pilot", "lock_wait"} <= parts
+        (req,) = _spans(spans, "request")
+        assert req["tid"] == submit["tid"]
+        assert req["ts"] == submit["ts"]
+        assert req["args"]["ticket"] == res.ticket
+        assert req["args"]["waves"] >= 1
+        assert req["args"]["rounds"] >= req["args"]["waves"]
+        assert req["args"]["queue_us"] >= 0
+        assert obs.m["grid_pilots"].value() >= \
+            1 + obs.m["grid_refits"].value()
+        if obs.m["grid_refits"].value():
+            refits = [p for s in _spans(spans, "plan")
+                      for p in s["args"].get("parts", ())
+                      if p[0] == "refit"]
+            assert refits
+
+    def test_every_wave_span_carries_its_wave(self, served_adaptive):
+        _, _, spans = served_adaptive
+        worker = _spans(spans, "launch")[0]["tid"]
+        on_worker = [s for s in spans if s["tid"] == worker]
+        assert {s["name"] for s in on_worker} >= {"plan", "launch",
+                                                   "lock_wait", "complete"}
+        for s in on_worker:
+            assert isinstance(s["args"].get("wave"), int), \
+                (s["name"], s["args"])
+        launches = {s["args"]["wave"] for s in _spans(spans, "launch")}
+        for name in ("device_execute", "transfer", "deposit"):
+            assert {s["args"]["wave"] for s in _spans(spans, name)} \
+                <= launches
+        plans = {s["args"]["wave"]: s for s in _spans(spans, "plan")}
+        for w in launches:
+            assert plans[w]["args"]["tickets"]
+        # journal writes outside a wave are the submit's grid and alloc
+        submits = _spans(spans, "submit")
+        for s in _spans(spans, "wal_commit"):
+            if "wave" not in s["args"]:
+                assert any(u["tid"] == s["tid"] and u["ts"] <= s["ts"]
+                           and s["ts"] + s["dur"] <= u["ts"] + u["dur"]
+                           for u in submits), s
+
+    def test_no_new_span_lies_inside_a_stage(self, served_adaptive):
+        _, _, spans = served_adaptive
+        stages = [s for s in spans if s["name"] in STAGES]
+        for s in spans:
+            # a span of the 1-us minimum can look nested by rounding alone
+            if s["name"] not in NEW_SPANS or s["dur"] <= 1:
+                continue
+            for t in stages:
+                assert not (t["tid"] == s["tid"] and t["ts"] <= s["ts"]
+                            and s["ts"] + s["dur"] <= t["ts"] + t["dur"]), \
+                    (s, t)
+
+    def test_plan_cache_hits_and_misses_cover_the_fused_groups(
+            self, make_engine, obs, events):
+        engine = make_engine(obs=obs, max_rounds_per_wave=1)
+        client = IntegrationClient(engine)
+        client.integrate([harmonic_family(3, 2)], n_samples=3 * R)
+        client.integrate([harmonic_family(3, 2), gaussian_family(2, 3)],
+                         n_samples=2 * R)
+        groups = sum(s["args"]["groups"] for s in _spans(events, "launch"))
+        hits = obs.m["plan_cache_hits"].value()
+        misses = obs.m["plan_cache_misses"].value()
+        assert hits >= 1 and misses >= 2
+        assert hits + misses == groups == engine.stats.waves
+        builds = [p for s in _spans(events, "launch")
+                  for p in s["args"]["parts"] if p[0] == "build"]
+        assert len(builds) == groups
+
+    def test_d2h_copies_counter_equals_the_copies_made(
+            self, make_engine, obs, monkeypatch):
+        import jax
+
+        from repro.service import batcher
+
+        copies = []
+
+        class CountingNumpy:
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+            def asarray(self, x, *args, **kwargs):
+                if isinstance(x, jax.Array):
+                    copies.append(1)
+                return np.asarray(x, *args, **kwargs)
+
+        monkeypatch.setattr(batcher, "np", CountingNumpy())
+        engine = make_engine(obs=obs)
+        client = IntegrationClient(engine)
+        client.integrate([harmonic_family(3, 2)], n_samples=3 * R)
+        client.integrate([gaussian_family(2, 2), harmonic_family(2, 2)],
+                         n_samples=2 * R)
+        assert copies
+        assert obs.m["d2h_copies"].value() == len(copies)
