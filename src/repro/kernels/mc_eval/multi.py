@@ -40,9 +40,13 @@ Multi-round plans: :func:`eval_plan_rounds` (and its mesh sibling
 counter rounds of every bucket in ONE launch each — a refinement wave of
 R rounds costs B launches instead of R x B.  Per-family ``start_rounds``
 ride in a per-function-block SMEM operand, so streams parked at
-different refinement depths still share the launch; per-round sums are
-bit-identical to the R single-round launches they replace (the service
-cache's in-order fold and resume invariants depend on this).
+different refinement depths still share the launch.  Each bucket's
+``(R, rows, 2)`` output comes back whole, beside its slices, and no
+device operation follows the launch: :func:`split_rounds` reads a stack
+to the host with one copy and cuts it per family and round there.  The
+per-round sums are bit-identical to the R single-round launches they
+replace (the service cache's in-order fold and resume invariants depend
+on this).
 """
 
 from __future__ import annotations
@@ -253,17 +257,17 @@ def eval_plan_rounds(plan: FusionPlan, round_samples: int, n_rounds: int,
         may start at different depths (fused top-ups).
       part: ``part(name, label)`` -> context manager timing one step:
         ``dispatch`` of the shared scalars (label ``scalars``), then per
-        bucket its ``dispatch`` (operands and ``fused_mc_pallas``) and
-        ``unpack`` (per-round slicing), labelled with the kernel's name;
-        a trace span's ``part`` (:mod:`repro.obs.trace`).  None times
-        nothing.
+        bucket its ``dispatch`` (operands and ``fused_mc_pallas``),
+        labelled with the kernel's name; a trace span's ``part``
+        (:mod:`repro.obs.trace`).  None times nothing.
     Returns:
-      {family_index: (SumsState, ...)} — ``n_rounds`` states in round
-      order, each bit-identical to the single-round
+      ``((sums, slices), ...)``, one pair per bucket: ``sums`` is the
+      launch's own f32 ``(n_rounds, rows, 2)`` output, still a device
+      future (nothing is dispatched after the kernel), and ``slices``
+      says where each family's rows lie.  :func:`split_rounds` turns it
+      into per-family rounds, each bit-identical to the single-round
       :func:`eval_plan` call at ``sample_offset = round * round_samples``.
     """
-    from repro.core.direct_mc import SumsState
-
     interpret = resolve_interpret(interpret)
     n_sample_blocks = max(1, math.ceil(int(round_samples) / S_BLK))
     part = part or _untimed
@@ -271,7 +275,7 @@ def eval_plan_rounds(plan: FusionPlan, round_samples: int, n_rounds: int,
         scalars = template.pack_scalars(key, 0, round_samples,
                                         round_stride=round_samples)
 
-    out: dict[int, tuple] = {}
+    out = []
     for bucket in plan.buckets:
         name = f"{bucket.name}_r{n_rounds}"
         with part("dispatch", name):
@@ -289,13 +293,31 @@ def eval_plan_rounds(plan: FusionPlan, round_samples: int, n_rounds: int,
                 n_sample_blocks=n_sample_blocks, bodies=bucket.bodies,
                 n_rounds=n_rounds, sampler=plan.sampler,
                 interpret=interpret, name=name)
-        with part("unpack", name):
-            for sl in bucket.slices:
-                rows = sums[:, sl.row_start:sl.row_start + sl.n_fn]
-                out[sl.family_index] = tuple(
-                    SumsState(s1=rows[r, :, 0], s2=rows[r, :, 1],
-                              n=jnp.float32(round_samples))
-                    for r in range(n_rounds))
+        out.append((sums, bucket.slices))
+    return tuple(out)
+
+
+def split_rounds(stacks, round_samples: int):
+    """Per-family rounds of :func:`eval_plan_rounds`'s (or
+    :func:`sharded_eval_plan_rounds`'s) output, cut on the host.
+
+    Each bucket's stack is read with ONE device-to-host copy (none if it
+    is a host array already); every round's ``s1``/``s2`` is a numpy view
+    of it and ``n`` a host ``np.float32``.  Returns
+    ``{family_index: (SumsState, ...)}``, ``n_rounds`` states in round
+    order.
+    """
+    from repro.core.direct_mc import SumsState
+
+    n = np.float32(round_samples)
+    out: dict[int, tuple] = {}
+    for sums, slices in stacks:
+        host = np.asarray(sums)
+        for sl in slices:
+            rows = host[:, sl.row_start:sl.row_start + sl.n_fn]
+            out[sl.family_index] = tuple(
+                SumsState(s1=rows[r, :, 0], s2=rows[r, :, 1], n=n)
+                for r in range(rows.shape[0]))
     return out
 
 
@@ -409,7 +431,8 @@ def sharded_eval_plan_rounds(plan: FusionPlan, round_samples: int,
                              fn_axis: str = "model", sample_axes=("data",),
                              interpret: bool | None = None, part=None):
     """Mesh variant of :func:`eval_plan_rounds`: R rounds x B buckets in
-    B launches, *inside* ``shard_map``; ``part`` as there.
+    B launches, *inside* ``shard_map``; ``part`` and the return as there
+    (a stack keeps the shard padding's rows past its last slice).
 
     Each sample-axis shard evaluates its window of every round (the last
     shard masks the tail, so each round draws exactly ``round_samples``
@@ -421,7 +444,6 @@ def sharded_eval_plan_rounds(plan: FusionPlan, round_samples: int,
     how many rounds ride in the stack.
     """
     from repro.compat import shard_map
-    from repro.core.direct_mc import SumsState
 
     interpret = resolve_interpret(interpret)
     sample_axes = tuple(sample_axes)
@@ -433,7 +455,7 @@ def sharded_eval_plan_rounds(plan: FusionPlan, round_samples: int,
     fs = P(fn_axis)
 
     part = part or _untimed
-    out: dict[int, tuple] = {}
+    out = []
     for bucket in plan.buckets:
         name = f"{bucket.name}_r{n_rounds}_sharded"
         with part("dispatch", name):
@@ -479,11 +501,5 @@ def sharded_eval_plan_rounds(plan: FusionPlan, round_samples: int,
             sums = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
                              out_specs=P(None, fn_axis),
                              check_vma=False)(*args)
-        with part("unpack", name):
-            for sl in bucket.slices:
-                rows = sums[:, sl.row_start:sl.row_start + sl.n_fn]
-                out[sl.family_index] = tuple(
-                    SumsState(s1=rows[r, :, 0], s2=rows[r, :, 1],
-                              n=jnp.float32(int(round_samples)))
-                    for r in range(n_rounds))
-    return out
+        out.append((sums, bucket.slices))
+    return tuple(out)

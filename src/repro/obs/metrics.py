@@ -306,8 +306,9 @@ def service_metrics(registry: MetricsRegistry) -> dict:
                                      cached (``RoundBatcher._plan_for``)
     zmc_plan_cache_misses_total      fused groups that built and uploaded a
                                      fresh plan (``multi.plan_spec``)
-    zmc_d2h_copies_total             device-to-host reads in ``transfer``
-                                     (three per stream and round)
+    zmc_d2h_copies_total             device-to-host copies of wave sums
+                                     (one per fused bucket, three per
+                                     chunked-fallback stream and round)
     zmc_backend_compiles_total       XLA backend compiles JAX reported
                                      (process-wide, from ``jax.monitoring``)
     zmc_compile_seconds              histogram {phase=trace|lower|backend}:
@@ -410,7 +411,8 @@ def service_metrics(registry: MetricsRegistry) -> dict:
             "fused launch groups that built a fresh fusion plan"),
         "d2h_copies": registry.counter(
             "zmc_d2h_copies_total",
-            "device-to-host reads of wave sums in the transfer stage"),
+            "device-to-host copies of wave sums: one per fused bucket, "
+            "three per fallback round"),
         "backend_compiles": registry.counter(
             "zmc_backend_compiles_total",
             "XLA backend compiles reported by jax.monitoring"),

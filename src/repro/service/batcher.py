@@ -23,7 +23,10 @@ as few kernel launches as possible:
 Evaluation is split into :meth:`RoundBatcher.launch` (device dispatch —
 returns an :class:`InFlightWave` whose sums are still device futures
 under JAX async dispatch) and :meth:`RoundBatcher.deposit` (host
-transfer + one group-committed cache fold per wave).  The engine
+transfer + one group-committed cache fold per wave).  A fused bucket's
+round stack crosses to the host whole, with one copy, and is cut per
+(stream, round) there: ``launch`` issues nothing after the kernel, so it
+returns without waiting for the device.  The engine
 pipelines the two: wave k+1's launch overlaps wave k's transfer and
 deposit, keeping journaling off the device critical path.
 :meth:`RoundBatcher.execute` composes them for synchronous drivers.
@@ -54,6 +57,7 @@ import collections
 import dataclasses
 from typing import Sequence
 
+import jax
 import numpy as np
 
 from repro.analysis import streams as _analysis
@@ -81,13 +85,57 @@ class _Span:
     count: int
 
 
+class _FusedStack:
+    """One fused bucket's ``(n_rounds, rows, 2)`` sums: a device future
+    until first read, then ONE device-to-host copy, split on the host
+    (:func:`repro.kernels.mc_eval.multi.split_rounds`)."""
+
+    def __init__(self, sums, slices, round_samples: int, copies):
+        self.device = sums
+        self._slices = slices
+        self._round_samples = round_samples
+        self._copies = copies
+        self._rounds = None
+
+    def rounds(self) -> dict[int, tuple[SumsState, ...]]:
+        if self._rounds is None:
+            from repro.kernels.mc_eval import multi
+            host = np.asarray(self.device)
+            self._copies.inc()
+            self._rounds = multi.split_rounds([(host, self._slices)],
+                                              self._round_samples)
+        return self._rounds
+
+
+class _HostView:
+    """``s1`` or ``s2`` of one (stream, round) in a fused bucket;
+    ``np.asarray`` reads it through the bucket's single host copy."""
+
+    __slots__ = ("stack", "family_index", "round", "field")
+
+    def __init__(self, stack: _FusedStack, family_index: int, round: int,
+                 field: str):
+        self.stack = stack
+        self.family_index = family_index
+        self.round = round
+        self.field = field
+
+    def __array__(self, dtype=None, copy=None):
+        rounds = self.stack.rounds()[self.family_index]
+        return np.array(getattr(rounds[self.round], self.field),
+                        dtype=dtype, copy=copy)
+
+
 @dataclasses.dataclass
 class InFlightWave:
     """A dispatched wave whose sums may still be computing on device.
 
     ``results`` holds ``(entry, round_index, sums)`` with each entry's
-    rounds ascending; the arrays inside ``sums`` are jax values — they
-    materialize (blocking on the device) in :meth:`RoundBatcher.deposit`.
+    rounds ascending.  A fused stream's ``sums`` are :class:`_HostView`
+    fields over its bucket's stack and a host ``np.float32`` ``n``; a
+    chunked-fallback stream's are jax values.  Either reads with
+    ``np.asarray``; :meth:`RoundBatcher.deposit` blocks on the device and
+    transfers whatever ``results`` holds when it runs.
     """
     results: list[tuple[CacheEntry, int, SumsState]]
     n_items: int
@@ -131,7 +179,10 @@ class RoundBatcher:
         Items are deduplicated (two requests wanting the same round of
         the same stream cost one evaluation), folded into per-stream
         contiguous spans, and spans sharing a round count are evaluated
-        by one fused multi-round launch per dimension bucket.
+        by one fused multi-round launch per dimension bucket.  Nothing
+        is dispatched after a bucket's kernel: its streams' sums are
+        host views over the kernel's output, read in :meth:`deposit`, so
+        this returns without waiting for the device.
         """
         obs = self.obs
         unique = sorted(set(items),
@@ -159,10 +210,13 @@ class RoundBatcher:
     def deposit(self, wave: InFlightWave) -> int:
         """Materialize a launched wave and group-commit it to the cache.
 
-        Blocks on the device results (wave k's transfer overlaps wave
-        k+1's dispatch when the engine pipelines), then folds every round
-        through :meth:`ResultCache.deposit_wave` — one WAL fsync for the
-        whole wave.  Returns the wave's item count.
+        Blocks once on the wave's device values, each fused bucket's
+        stack once (wave k's transfer overlaps wave k+1's kernel when the
+        engine pipelines); ``transfer`` then reads each stack with one
+        device-to-host copy and cuts every (stream, round) out of it on
+        the host, and each fallback round with three copies.  Every round
+        folds through :meth:`ResultCache.deposit_wave` — one WAL fsync
+        for the whole wave.  Returns the wave's item count.
         """
         obs = self.obs
         if _analysis.asserts_enabled():
@@ -177,19 +231,16 @@ class RoundBatcher:
                 # block on the device futures *before* converting, so
                 # the trace splits device wait from host-side transfer
                 self.faults.check("device_execute")
-                import jax
-                jax.block_until_ready([sums.s1 for _, _, sums
-                                       in wave.results])
+                jax.block_until_ready(_device_values(wave.results))
         with obs.span("transfer", items=wave.n_items) as span:
             self.faults.check("transfer")
             with span.part("copies"):
                 deposits = [
                     (entry, round_index,
-                     SumsState(s1=np.asarray(sums.s1, np.float32),
-                               s2=np.asarray(sums.s2, np.float32),
-                               n=np.float32(np.asarray(sums.n))))
+                     SumsState(s1=self._to_host(sums.s1),
+                               s2=self._to_host(sums.s2),
+                               n=np.float32(self._to_host(sums.n))))
                     for entry, round_index, sums in wave.results]
-            obs.m["d2h_copies"].inc(3 * len(deposits))
             if (self.faults.enabled and deposits
                     and self.faults.fire("transfer_nan")):
                 # poison the wave's first deposit: the cache's finite
@@ -202,6 +253,14 @@ class RoundBatcher:
             self.faults.check("deposit")
             self.cache.deposit_wave(deposits)
         return wave.n_items
+
+    def _to_host(self, value) -> np.ndarray:
+        """One field of a round's sums as f32 host data; a jax value is
+        a device-to-host copy of its own (a fused bucket counts its one
+        copy in :meth:`_FusedStack.rounds`)."""
+        if isinstance(value, jax.Array):
+            self.obs.m["d2h_copies"].inc()
+        return np.asarray(value, np.float32)
 
     # -- wave shaping ---------------------------------------------------------
     def _spans_of(self, unique: Sequence[WorkItem]) -> list[_Span]:
@@ -225,7 +284,7 @@ class RoundBatcher:
 
     def _launch_group(self, spans: list[_Span], trace_span):
         """One fused multi-round evaluation of same-count spans; its
-        plan build, dispatch and unpack are parts of ``trace_span``.
+        plan build and dispatch are parts of ``trace_span``.
         Returns the group's ``(entry, round, sums)`` and whether its
         fusion plan was cached (None when nothing was fused)."""
         n = self.cache.round_samples
@@ -242,7 +301,7 @@ class RoundBatcher:
         healthy = [sp for sp in spans if not sp.entry.degraded]
         degraded = [sp for sp in spans if sp.entry.degraded]
 
-        fused: dict[int, tuple] = {}
+        stacks = ()
         hit = None
         if self.use_kernel and healthy:
             entries = [sp.entry for sp in healthy]
@@ -256,20 +315,28 @@ class RoundBatcher:
                                            fn_offsets)
             start_rounds = {i: sp.start for i, sp in enumerate(healthy)}
             if self.mesh is not None:
-                fused = multi.sharded_eval_plan_rounds(
+                stacks = multi.sharded_eval_plan_rounds(
                     plan, n, count, self.key, self.mesh,
                     start_rounds=start_rounds, fn_axis=self.fn_axis,
                     sample_axes=self.sample_axes, part=trace_span.part)
             else:
-                fused = multi.eval_plan_rounds(
+                stacks = multi.eval_plan_rounds(
                     plan, n, count, self.key, start_rounds=start_rounds,
                     part=trace_span.part)
 
+        fused = {}
+        for sums, slices in stacks:
+            stack = _FusedStack(sums, slices, n, self.obs.m["d2h_copies"])
+            for sl in slices:
+                fused[sl.family_index] = stack
         out = []
         for idx, sp in enumerate(healthy):
             if idx in fused:
                 for r in range(count):
-                    out.append((sp.entry, sp.start + r, fused[idx][r]))
+                    out.append((sp.entry, sp.start + r, SumsState(
+                        s1=_HostView(fused[idx], idx, r, "s1"),
+                        s2=_HostView(fused[idx], idx, r, "s2"),
+                        n=np.float32(n))))
                 continue
             out.extend(self._chunked_rounds(sp, count, n, sampler))
         for sp in degraded:
@@ -323,3 +390,16 @@ class RoundBatcher:
         while len(self._plans) > self.plan_cache_size:
             self._plans.popitem(last=False)
         return plan, False
+
+
+def _device_values(results) -> list:
+    """What a wave's sums still wait on: each fused bucket's stack once,
+    and the fallback rounds' own arrays."""
+    out = {}
+    for _, _, sums in results:
+        for value in sums:
+            if isinstance(value, _HostView):
+                value = value.stack.device
+            if isinstance(value, jax.Array):
+                out[id(value)] = value
+    return list(out.values())

@@ -156,8 +156,8 @@ def test_multiround_compactified_bit_identical():
     """R rounds of a mixed finite/infinite bucket in one launch: each
     round bit-identical to its own single-round launch."""
     plan = multi.plan_spec(_mixed_spec())
-    fused = multi.eval_plan_rounds(plan, R, 3, KEY,
-                                   start_rounds={0: 0, 1: 0, 2: 0})
+    fused = multi.split_rounds(multi.eval_plan_rounds(
+        plan, R, 3, KEY, start_rounds={0: 0, 1: 0, 2: 0}), R)
     for r in range(3):
         single = multi.eval_plan(plan, R, KEY, sample_offset=r * R)
         for fam in single:
@@ -178,9 +178,10 @@ def test_sharded_compactified_matches_single_device():
         np.testing.assert_array_equal(np.asarray(single[i].s2),
                                       np.asarray(sharded[i].s2))
     starts = {0: 2, 1: 0, 2: 1}
-    fused = multi.eval_plan_rounds(plan, R, 2, KEY, start_rounds=starts)
-    shr = multi.sharded_eval_plan_rounds(plan, R, 2, KEY, mesh,
-                                         start_rounds=starts)
+    fused = multi.split_rounds(multi.eval_plan_rounds(
+        plan, R, 2, KEY, start_rounds=starts), R)
+    shr = multi.split_rounds(multi.sharded_eval_plan_rounds(
+        plan, R, 2, KEY, mesh, start_rounds=starts), R)
     for i in fused:
         for r in range(2):
             np.testing.assert_array_equal(np.asarray(fused[i][r].s1),

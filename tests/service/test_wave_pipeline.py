@@ -38,8 +38,8 @@ def test_eval_plan_rounds_bit_identical(sampler):
         [harmonic_family(6, 3), gaussian_family(4, 3)])
     plan = multi.plan_spec(spec, sampler=sampler)
     key = rng_lib.fold_key(4, 0)
-    fused = multi.eval_plan_rounds(plan, R, 3, key,
-                                   start_rounds={0: 0, 1: 0})
+    fused = multi.split_rounds(multi.eval_plan_rounds(
+        plan, R, 3, key, start_rounds={0: 0, 1: 0}), R)
     for r in range(3):
         single = multi.eval_plan(plan, R, key, sample_offset=r * R)
         for fam in single:
@@ -56,8 +56,8 @@ def test_eval_plan_rounds_heterogeneous_starts():
         [harmonic_family(6, 3), gaussian_family(4, 3)])
     plan = multi.plan_spec(spec)
     key = rng_lib.fold_key(4, 0)
-    fused = multi.eval_plan_rounds(plan, R, 2, key,
-                                   start_rounds={0: 2, 1: 0})
+    fused = multi.split_rounds(multi.eval_plan_rounds(
+        plan, R, 2, key, start_rounds={0: 2, 1: 0}), R)
     for fam, start in ((0, 2), (1, 0)):
         for r in range(2):
             single = multi.eval_plan(plan, R, key,
@@ -74,15 +74,154 @@ def test_sharded_eval_plan_rounds_bit_identical():
     plan = multi.plan_spec(spec)
     key = rng_lib.fold_key(4, 0)
     starts = {0: 1, 1: 0}
-    sharded = multi.sharded_eval_plan_rounds(plan, R, 2, key, mesh,
-                                             start_rounds=starts)
-    fused = multi.eval_plan_rounds(plan, R, 2, key, start_rounds=starts)
+    sharded = multi.split_rounds(multi.sharded_eval_plan_rounds(
+        plan, R, 2, key, mesh, start_rounds=starts), R)
+    fused = multi.split_rounds(multi.eval_plan_rounds(
+        plan, R, 2, key, start_rounds=starts), R)
     for fam in fused:
         for r in range(2):
             np.testing.assert_array_equal(np.asarray(sharded[fam][r].s1),
                                           np.asarray(fused[fam][r].s1))
             np.testing.assert_array_equal(np.asarray(sharded[fam][r].s2),
                                           np.asarray(fused[fam][r].s2))
+
+
+# -- batcher layer: one host copy per fused bucket ----------------------------
+
+def _wave_of(engine, fams, starts, count, degraded=()):
+    """Entries for ``fams`` in ``engine``'s cache (those indexed in
+    ``degraded`` marked so), and ``count`` rounds of each from its start."""
+    from repro.service.batcher import WorkItem
+    entries = [engine.cache.get_or_allocate(f"{i:064x}", fam)
+               for i, fam in enumerate(fams)]
+    for i in degraded:
+        entries[i].degraded = True
+    items = [WorkItem(e.chash, s + r, "mc")
+             for e, s in zip(entries, starts) for r in range(count)]
+    return entries, items
+
+
+def _single_round(engine, fams, entries, family_index, round_index):
+    """The round as one single-round fused launch computes it."""
+    from repro.core import MultiFunctionSpec
+    plan = multi.plan_spec(MultiFunctionSpec(families=tuple(fams)),
+                           fn_offsets=[e.fn_offset for e in entries])
+    return multi.eval_plan(plan, R, engine.key,
+                           sample_offset=round_index * R)[family_index]
+
+
+def test_batcher_host_split_bit_identical_to_single_round_launches(
+        make_engine):
+    """Several streams over two dim buckets, three rounds each from
+    different depths: every (stream, round) cut from the bucket stacks on
+    the host equals its own single-round launch, bit for bit."""
+    engine = make_engine()
+    fams = [harmonic_family(6, 3), gaussian_family(4, 3),
+            harmonic_family(5, 2)]
+    starts = (0, 2, 1)
+    entries, items = _wave_of(engine, fams, starts, 3)
+    template.reset_launch_count()
+    wave = engine.batcher.launch(items)
+    assert template.launch_count() == 2
+    got = {(e.chash, r): s for e, r, s in wave.results}
+    assert len(got) == 9
+    for i, (entry, start) in enumerate(zip(entries, starts)):
+        for r in range(start, start + 3):
+            single = _single_round(engine, fams, entries, i, r)
+            sums = got[(entry.chash, r)]
+            np.testing.assert_array_equal(np.asarray(sums.s1),
+                                          np.asarray(single.s1))
+            np.testing.assert_array_equal(np.asarray(sums.s2),
+                                          np.asarray(single.s2))
+            assert float(sums.n) == float(single.n) == R
+
+
+def test_launch_returns_device_futures_only(make_engine, monkeypatch):
+    """``launch`` dispatches nothing after the kernel: a fused stream's
+    sums are host views over the kernel's own output.  ``deposit`` then
+    reads each fused bucket with one copy, and a degraded stream's
+    chunked rounds with three copies each."""
+    outputs = []
+    kernel = template.fused_mc_pallas
+
+    def recording(*args, **kwargs):
+        out = kernel(*args, **kwargs)
+        outputs.append(out)
+        return out
+
+    monkeypatch.setattr(template, "fused_mc_pallas", recording)
+    engine = make_engine()
+    fams = [harmonic_family(6, 3), gaussian_family(4, 3),
+            harmonic_family(5, 2), harmonic_family(3, 3)]
+    count = 2
+    entries, items = _wave_of(engine, fams, (0, 0, 0, 0), count,
+                              degraded=(3,))
+    wave = engine.batcher.launch(items)
+    stacks = set()
+    for entry, _, sums in wave.results:
+        if entry.degraded:
+            assert all(isinstance(v, jax.Array) for v in sums)
+            continue
+        assert not any(isinstance(v, jax.Array) for v in sums)
+        assert isinstance(sums.n, np.float32)
+        assert sums.s1.stack is sums.s2.stack
+        assert any(sums.s1.stack.device is out for out in outputs)
+        stacks.add(id(sums.s1.stack))
+    assert len(stacks) == 2            # the dim-2 and dim-3 buckets
+    copies = engine.obs.m["d2h_copies"]
+    before = copies.value()
+    assert engine.batcher.deposit(wave) == 4 * count
+    assert copies.value() - before == len(stacks) + 3 * count
+    assert [e.rounds_done for e in entries] == [count] * 4
+
+
+def _as_host_triples(results, shift=0.0):
+    """``results`` rewritten as the benchmark's fault planting does:
+    host copies of every field, ``s1`` moved by ``shift``."""
+    from repro.core.direct_mc import SumsState
+    return [(entry, r, SumsState(
+        s1=np.asarray(s.s1, np.float32).copy() + np.float32(shift),
+        s2=np.asarray(s.s2, np.float32).copy(), n=np.asarray(s.n)))
+        for entry, r, s in results]
+
+
+def test_rewritten_results_are_what_deposit_folds(make_engine):
+    engine = make_engine()
+    fams = [harmonic_family(6, 3), harmonic_family(5, 2)]
+    entries, items = _wave_of(engine, fams, (0, 0), 2)
+    wave = engine.batcher.launch(items)
+    copies = engine.obs.m["d2h_copies"]
+    before = copies.value()
+    wave.results = _as_host_triples(wave.results, shift=1.0)
+    assert copies.value() - before == 2   # the rewrite read each bucket
+    engine.batcher.deposit(wave)
+    assert copies.value() - before == 2   # host triples cost no copy
+    for entry in entries:
+        mine = [s for e, _, s in wave.results if e is entry]
+        np.testing.assert_array_equal(entry.s1, mine[0].s1 + mine[1].s1)
+        assert entry.rounds_done == 2
+
+
+def test_transfer_nan_on_rewritten_fused_wave_is_not_journaled(
+        make_engine, tmp_path):
+    from repro.service.faults import FaultPlan
+    from repro.service.store import read_journal
+    engine = make_engine(state_dir=str(tmp_path),
+                         faults=FaultPlan({"transfer_nan": 0}))
+    fams = [harmonic_family(6, 3), harmonic_family(5, 2)]
+    entries, items = _wave_of(engine, fams, (0, 0), 2)
+    wave = engine.batcher.launch(items)
+    wave.results = _as_host_triples(wave.results)
+    poisoned = wave.results[0][0]
+    engine.batcher.deposit(wave)
+    assert poisoned.rounds_done == 0 and poisoned.poison_strikes == 1
+    healthy = [e for e in entries if e is not poisoned]
+    assert [e.rounds_done for e in healthy] == [2]
+    records, _ = read_journal(engine.store.journal_path)
+    journaled = [rec["chash"] for rec in records if rec["t"] == "dep"]
+    assert poisoned.chash not in journaled
+    assert journaled == [healthy[0].chash] * 2
+    engine.close()
 
 
 # -- engine layer: multi-round waves == single-round waves --------------------
